@@ -17,7 +17,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.launch.hlo_analysis import HBM_BW
@@ -44,7 +43,7 @@ def make_step(mesh: Mesh):
         new = _stencil(padded)
         return new
 
-    return shard_map(step, mesh=mesh, in_specs=P("x", None), out_specs=P("x", None))
+    return jax.shard_map(step, mesh=mesh, in_specs=P("x", None), out_specs=P("x", None))
 
 
 def run(sizes=(256, 512, 1024), iters: int = 5) -> list[dict]:

@@ -90,12 +90,12 @@ def main() -> None:
     ap = argparse.ArgumentParser(description="paged-attention microbenchmark")
     ap.add_argument(
         "--autotune", action="store_true",
-        help="sweep kernel layout knobs over the benchmark CASES and write "
-        "winners to the user autotune cache (see repro.kernels.autotune)",
+        help="sweep kernel layout knobs over the benchmark CASES on a TPU and "
+        "write winners to the committed table (see repro.kernels.autotune)",
     )
     ap.add_argument("--iters", type=int, default=5, help="timing reps per candidate")
     ap.add_argument("--dtype", default="bfloat16", help="pool dtype for the sweep")
-    ap.add_argument("--out", default=None, help="autotune cache path override")
+    ap.add_argument("--out", default=None, help="autotune table path override")
     args = ap.parse_args()
     if args.autotune:
         from repro.kernels.autotune import autotune

@@ -13,7 +13,7 @@ traffic, and the MXU's fp8 throughput is 2x bf16 on GH200-class parts), while
 every multiply-accumulate happens in fp32.  The per-tensor scales ride in
 SMEM and divide the accumulator once, on the final k step.
 
-On this CPU image the kernel runs through ``interpret=True``; TPU is the
+Off-TPU the kernel runs in interpret mode (``utils.platform``); TPU is the
 target.  ``repro.fp8.gemm_ref.fp8_gemm_ref`` is the pure-jnp oracle.
 """
 
@@ -26,11 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils.platform import resolve_interpret
+
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bn, bk)
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _fp8_gemm_kernel(a_scale_ref, b_scale_ref, a_ref, b_ref, o_ref, acc_ref):
@@ -79,8 +77,6 @@ def fp8_gemm(
     zero-padded up (fp8 zero is exact, padding contributes nothing) and the
     output sliced back.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     (M, K), (K2, N) = a.shape, b.shape
     assert K == K2, (a.shape, b.shape)
     bm, bn, bk = (min(block[0], M), min(block[1], N), min(block[2], K))
@@ -100,6 +96,6 @@ def fp8_gemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_scale.reshape(1).astype(jnp.float32), b_scale.reshape(1).astype(jnp.float32), a, b)
     return out[:M, :N]

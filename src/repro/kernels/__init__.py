@@ -2,8 +2,9 @@
 
 Each kernel ships as a triple: ``<name>.py`` (pl.pallas_call + BlockSpec),
 ``<name>_ops.py`` (jit'd public wrapper) and ``<name>_ref.py`` (pure-jnp
-oracle used by the allclose test sweeps).  TPU is the TARGET; on this CPU
-image everything runs through ``interpret=True``.
+oracle used by the allclose test sweeps).  TPU is the target; on any other
+backend the kernels run in interpret mode, decided in one place
+(``utils.platform.resolve_interpret``).
 """
 
 from repro.fp8.gemm import fp8_gemm

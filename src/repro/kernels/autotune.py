@@ -16,28 +16,26 @@ problem shape, not the code:
 ``autotune()`` times every candidate per case with the same
 block-until-ready loop as ``benchmarks/paged_attention.py`` (which exposes
 the sweep as ``--autotune``) and records the winner under a key derived
-from ``(head_dim, block_size, page_count, dtype)``.  Lookup order:
+from ``(head_dim, block_size, page_count, dtype)`` in the committed
+``src/repro/kernels/autotune_defaults.json``.  Lookup reads that file
+alone — an exact key, then the any-page-count key, then ``"default"`` — so
+what gets compiled depends only on the checkout, never on files elsewhere
+on the host.  The sweep refuses to run off-TPU: interpret-mode timings say
+nothing about the chip.
 
-1. user cache — ``$REPRO_AUTOTUNE_CACHE`` or
-   ``~/.cache/repro/pallas_autotune.json`` (written by ``autotune()``)
-2. in-repo defaults — ``src/repro/kernels/autotune_defaults.json``
-3. the ``"default"`` entry of either file
-
-``get_config`` is pure given the cache files (no timing at lookup), so a
-compiled graph's layout is deterministic — the CI ``fused-step`` lane
-asserts that two lookups and a cache round-trip agree byte-for-byte.
+``get_config`` is pure given the file (no timing at lookup), so a compiled
+graph's layout is deterministic — the CI ``fused-step`` lane asserts that
+two lookups and a table round-trip agree byte-for-byte.
 """
 
 from __future__ import annotations
 
 import json
-import os
 import time
 from functools import lru_cache
 from pathlib import Path
 
 DEFAULTS_PATH = Path(__file__).with_name("autotune_defaults.json")
-CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 
 DECODE_KERNELS = ("paged", "prefill1")
 ROW_TILE_CANDIDATES = (0, 8, 16, 32)  # 0 = one tile holding every row
@@ -47,13 +45,6 @@ _DEFAULT_CONFIG = {"prefill_rows_per_tile": 0, "decode_kernel": "paged"}
 
 def cache_key(head_dim: int, block_size: int, page_count: int, dtype) -> str:
     return f"hd{head_dim}_bs{block_size}_pages{page_count}_{str(dtype)}"
-
-
-def user_cache_path() -> Path:
-    env = os.environ.get(CACHE_ENV)
-    if env:
-        return Path(env)
-    return Path.home() / ".cache" / "repro" / "pallas_autotune.json"
 
 
 def _read_json(path: Path) -> dict:
@@ -66,17 +57,15 @@ def _read_json(path: Path) -> dict:
 
 
 @lru_cache(maxsize=None)
-def _load_table(defaults: str, user: str) -> dict:
-    table = _read_json(Path(defaults))
-    table.update(_read_json(Path(user)))
-    return table
+def _load_table(path: str) -> dict:
+    return _read_json(Path(path))
 
 
 def load_table(refresh: bool = False) -> dict:
-    """Merged tuning table (user cache entries shadow in-repo defaults)."""
+    """The committed tuning table (``autotune_defaults.json``)."""
     if refresh:
         _load_table.cache_clear()
-    return _load_table(str(DEFAULTS_PATH), str(user_cache_path()))
+    return _load_table(str(DEFAULTS_PATH))
 
 
 def _sanitize(entry) -> dict:
@@ -145,7 +134,6 @@ def tune_case(B: int, nb: int, bs: int, H: int, KV: int, hd: int, dtype="bfloat1
 
     from repro.kernels.paged_attention import paged_attention_bhd, paged_prefill_attention_bhd
 
-    interpret = jax.default_backend() != "tpu"
     dt = jnp.dtype(dtype)
     q1, qc, k_pool, v_pool, tbl, lens = _build_case(B, nb, bs, H, KV, hd, dt)
 
@@ -156,7 +144,7 @@ def tune_case(B: int, nb: int, bs: int, H: int, KV: int, hd: int, dtype="bfloat1
             continue
         fn = jax.jit(
             lambda q, k, v, t, s, _rt=rt: paged_prefill_attention_bhd(
-                q, k, v, t, s, interpret=interpret, rows_per_tile=_rt
+                q, k, v, t, s, rows_per_tile=_rt
             )
         )
         dt_s = _time(fn, qc, k_pool, v_pool, tbl, jnp.zeros((B,), jnp.int32), iters=iters)
@@ -165,12 +153,10 @@ def tune_case(B: int, nb: int, bs: int, H: int, KV: int, hd: int, dtype="bfloat1
 
     decode_fns = {
         "paged": jax.jit(
-            lambda q, k, v, t, sl: paged_attention_bhd(q, k, v, t, sl, interpret=interpret)
+            lambda q, k, v, t, sl: paged_attention_bhd(q, k, v, t, sl)
         ),
         "prefill1": jax.jit(
-            lambda q, k, v, t, sl: paged_prefill_attention_bhd(
-                q[:, None], k, v, t, sl - 1, interpret=interpret
-            )[:, 0]
+            lambda q, k, v, t, sl: paged_prefill_attention_bhd(q[:, None], k, v, t, sl - 1)[:, 0]
         ),
     }
     best_dk, best_dk_t = "paged", float("inf")
@@ -188,9 +174,16 @@ def tune_case(B: int, nb: int, bs: int, H: int, KV: int, hd: int, dtype="bfloat1
 
 
 def autotune(cases, dtype="bfloat16", iters: int = 5, out_path: Path | None = None) -> dict:
-    """Sweep ``cases`` (tuples of (B, nb, block_size, H, KV, hd)) and write
-    the winners to the user cache (creating parent dirs)."""
-    out_path = Path(out_path) if out_path else user_cache_path()
+    """Sweep ``cases`` (tuples of (B, nb, block_size, H, KV, hd)) on the TPU
+    and write the winners to ``out_path`` (default: the committed table)."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"autotune sweeps only on a TPU (backend is {jax.default_backend()!r}): "
+            "interpret-mode timings must not become kernel layouts"
+        )
+    out_path = Path(out_path) if out_path else DEFAULTS_PATH
     table = _read_json(out_path)
     for B, nb, bs, H, KV, hd in cases:
         won = tune_case(B, nb, bs, H, KV, hd, dtype=dtype, iters=iters)
@@ -231,7 +224,7 @@ def main(argv=None) -> None:
     ap.add_argument("--check", action="store_true", help="verify cache determinism, no sweep")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--dtype", default="bfloat16")
-    ap.add_argument("--out", default=None, help="cache path (default: user cache)")
+    ap.add_argument("--out", default=None, help="table path (default: the committed defaults)")
     args = ap.parse_args(argv)
     if args.check:
         check_determinism()

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.utils.platform import resolve_interpret
+
 DEFAULT_BLOCK = 65_536  # elements per tile: 256 KiB f32 -> fits VMEM 2x-buffered
 
 
@@ -55,7 +57,7 @@ def _spec(block: int):
     return pl.BlockSpec((block,), lambda i: (i,))
 
 
-def stream_copy(a: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool = True) -> jax.Array:
+def stream_copy(a: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool | None = None) -> jax.Array:
     n = a.shape[0]
     return pl.pallas_call(
         _copy_kernel,
@@ -63,11 +65,11 @@ def stream_copy(a: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool = T
         in_specs=[_spec(block)],
         out_specs=_spec(block),
         out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a)
 
 
-def stream_mul(c: jax.Array, scalar: float = 0.4, *, block: int = DEFAULT_BLOCK, interpret: bool = True) -> jax.Array:
+def stream_mul(c: jax.Array, scalar: float = 0.4, *, block: int = DEFAULT_BLOCK, interpret: bool | None = None) -> jax.Array:
     n = c.shape[0]
     return pl.pallas_call(
         functools.partial(_mul_kernel, scalar=scalar),
@@ -75,11 +77,11 @@ def stream_mul(c: jax.Array, scalar: float = 0.4, *, block: int = DEFAULT_BLOCK,
         in_specs=[_spec(block)],
         out_specs=_spec(block),
         out_shape=jax.ShapeDtypeStruct((n,), c.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(c)
 
 
-def stream_add(a: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool = True) -> jax.Array:
+def stream_add(a: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool | None = None) -> jax.Array:
     n = a.shape[0]
     return pl.pallas_call(
         _add_kernel,
@@ -87,11 +89,11 @@ def stream_add(a: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK, interp
         in_specs=[_spec(block), _spec(block)],
         out_specs=_spec(block),
         out_shape=jax.ShapeDtypeStruct((n,), a.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)
 
 
-def stream_triad(b: jax.Array, c: jax.Array, scalar: float = 0.4, *, block: int = DEFAULT_BLOCK, interpret: bool = True) -> jax.Array:
+def stream_triad(b: jax.Array, c: jax.Array, scalar: float = 0.4, *, block: int = DEFAULT_BLOCK, interpret: bool | None = None) -> jax.Array:
     n = b.shape[0]
     return pl.pallas_call(
         functools.partial(_triad_kernel, scalar=scalar),
@@ -99,11 +101,11 @@ def stream_triad(b: jax.Array, c: jax.Array, scalar: float = 0.4, *, block: int 
         in_specs=[_spec(block), _spec(block)],
         out_specs=_spec(block),
         out_shape=jax.ShapeDtypeStruct((n,), b.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(b, c)
 
 
-def stream_dot(a: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool = True) -> jax.Array:
+def stream_dot(a: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK, interpret: bool | None = None) -> jax.Array:
     n = a.shape[0]
     partials = pl.pallas_call(
         _dot_kernel,
@@ -111,7 +113,7 @@ def stream_dot(a: jax.Array, b: jax.Array, *, block: int = DEFAULT_BLOCK, interp
         in_specs=[_spec(block), _spec(block)],
         out_specs=pl.BlockSpec((1,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n // block,), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)
     return jnp.sum(partials)
 

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.utils.platform import resolve_interpret
+
 NEG_INF = -1.0e38
 
 
@@ -101,7 +103,7 @@ def flash_attention_bhsd(
     softcap: float = 0.0,
     q_block: int = 128,
     kv_block: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]
@@ -136,5 +138,5 @@ def flash_attention_bhsd(
             pl_scratch((q_block, 1), jnp.float32),
             pl_scratch((q_block, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the flash attention kernel.
 
 Accepts the model's (B, S, H, hd) layout, transposes to the kernel's
-head-major layout, and picks interpret mode automatically off-TPU.
+head-major layout; interpret mode off-TPU comes from ``utils.platform``.
 """
 
 from __future__ import annotations
@@ -12,10 +12,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_bhsd
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap", "q_block", "kv_block"))
@@ -45,6 +41,5 @@ def flash_attention(
         softcap=softcap,
         q_block=qb,
         kv_block=kb,
-        interpret=not _on_tpu(),
     )
     return out.transpose(0, 2, 1, 3)

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the paged-attention kernels (decode + chunked
 prefill).
 
-Routes fp pools through the Pallas kernels (interpret mode off-TPU);
+Routes fp pools through the Pallas kernels (interpret mode off-TPU, chosen
+by ``utils.platform``);
 quantized (int8/fp8) pools with per-(token, head) scales fall back to the
 dequantizing jnp reference — the quantization savings are an HBM-traffic
 property, and on this CPU image both paths are emulated anyway.
@@ -26,16 +27,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import autotune
 from repro.kernels.paged_attention import paged_attention_bhd, paged_prefill_attention_bhd
 from repro.kernels.paged_attention_ref import paged_attention_ref, paged_prefill_attention_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _quantized_pool(dtype) -> bool:
@@ -56,28 +52,32 @@ def kernel_shardable(mesh, num_q_heads: int, num_kv_heads: int) -> bool:
     must run per-shard under ``shard_map`` on its local head slice.  That
     requires BOTH head counts to divide the model axis (the contiguous
     per-device q-head slice then stays aligned with its GQA kv group).
-    Callers fall back to the XLA reference path when this returns False."""
+    The engine refuses ``attn_impl="pallas"`` on a mesh where this is False."""
     tp = model_axis_size(mesh)
     if tp <= 1:
         return True
     return num_q_heads % tp == 0 and num_kv_heads % tp == 0
 
 
-def _tp_dispatch(mesh, kernel, ref, q_spec, num_q_heads: int, num_kv_heads: int):
+def _tp_dispatch(mesh, kernel, q_spec, num_q_heads: int, num_kv_heads: int):
     """One TP dispatch rule for both paged kernels: per-shard ``shard_map``
-    on the local head slice when the head counts divide, else the jnp
-    reference (which GSPMD partitions freely).  ``q_spec`` is the query (and
-    output) PartitionSpec — the only thing that differs between the decode
-    (B, H, hd) and chunked-prefill (B, C, H, hd) entry points."""
+    on the local head slice.  Head counts that don't divide the model axis
+    raise: running the jnp reference instead would hide that the kernel never
+    ran.  ``q_spec`` is the query (and output) PartitionSpec — the only thing
+    that differs between the decode (B, H, hd) and chunked-prefill
+    (B, C, H, hd) entry points."""
     if not kernel_shardable(mesh, num_q_heads, num_kv_heads):
-        return ref
+        raise ValueError(
+            f"Pallas paged attention can't take a head slice: {num_q_heads} q / "
+            f"{num_kv_heads} kv heads over a model axis of {model_axis_size(mesh)}"
+        )
     pool = P(None, None, "model", None)  # every block, local head slice
-    return shard_map(
+    return jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(q_spec, pool, pool, P(None, None), P(None)),
         out_specs=q_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -105,7 +105,6 @@ def paged_attention(
             paged_prefill_attention_bhd,
             softcap=softcap,
             window=window,
-            interpret=not _on_tpu(),
             rows_per_tile=tuned["prefill_rows_per_tile"],
         )
 
@@ -113,17 +112,11 @@ def paged_attention(
             return base(qq[:, None], kk, vv, tbl, lens - 1)[:, 0]
 
     else:
-        kernel = partial(
-            paged_attention_bhd,
-            softcap=softcap,
-            window=window,
-            interpret=not _on_tpu(),
-        )
+        kernel = partial(paged_attention_bhd, softcap=softcap, window=window)
     if model_axis_size(mesh) > 1:
         kernel = _tp_dispatch(
             mesh,
             kernel,
-            partial(paged_attention_ref, softcap=softcap, window=window),
             P(None, "model", None),
             q.shape[1],
             k_pool.shape[2],
@@ -152,14 +145,12 @@ def paged_prefill_attention(
         paged_prefill_attention_bhd,
         softcap=softcap,
         window=window,
-        interpret=not _on_tpu(),
         rows_per_tile=tuned["prefill_rows_per_tile"],
     )
     if model_axis_size(mesh) > 1:
         kernel = _tp_dispatch(
             mesh,
             kernel,
-            partial(paged_prefill_attention_ref, softcap=softcap, window=window),
             P(None, None, "model", None),
             q.shape[2],
             k_pool.shape[2],

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.flash_attention import pl_scratch
+from repro.utils.platform import resolve_interpret
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *, chunk: int):
@@ -76,7 +77,7 @@ def wkv6_chunked(
     u: jax.Array,  # (BH, n) per-head bonus
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     BH, S, n = r.shape
     assert S % chunk == 0, (S, chunk)
@@ -95,5 +96,5 @@ def wkv6_chunked(
         out_specs=pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, n), jnp.float32),
         scratch_shapes=[pl_scratch((n, n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, logw, u)

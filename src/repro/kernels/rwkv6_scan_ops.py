@@ -10,10 +10,6 @@ import jax.numpy as jnp
 from repro.kernels.rwkv6_scan import wkv6_chunked
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("chunk",))
 def wkv6(r, k, v, logw, u, *, chunk: int = 64):
     """r,k,v,logw: (B, S, H, n); u: (H, n). Returns (B, S, H, n) fp32."""
@@ -23,7 +19,5 @@ def wkv6(r, k, v, logw, u, *, chunk: int = 64):
         return x.transpose(0, 2, 1, 3).reshape(B * H, S, n)
 
     ub = jnp.broadcast_to(u[None], (B, H, n)).reshape(B * H, n)
-    out = wkv6_chunked(
-        to_bh(r), to_bh(k), to_bh(v), to_bh(logw), ub, chunk=chunk, interpret=not _on_tpu()
-    )
+    out = wkv6_chunked(to_bh(r), to_bh(k), to_bh(v), to_bh(logw), ub, chunk=chunk)
     return out.reshape(B, H, S, n).transpose(0, 2, 1, 3)

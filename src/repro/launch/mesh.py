@@ -10,8 +10,16 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.config import MeshConfig
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules place
+    arrays with ``NamedSharding``s and let GSPMD propagate (the installed
+    JAX defaults new meshes to ``Explicit`` sharding-in-types)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(cfg: MeshConfig):
@@ -31,7 +39,7 @@ def make_mesh(cfg: MeshConfig):
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many (CPU) devices exist — for tests."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(tp: int = 1):

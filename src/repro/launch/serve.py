@@ -47,8 +47,8 @@ under pool pressure; ``fcfs`` ignores SLO knobs.
 
 ``--replicas N`` runs N independent engines behind the prefix-affinity
 ``serving.router.Router`` — each replica on its own ``(data=1, model=tp)``
-device slice when ``N*tp`` devices are visible (``launch.mesh
-.make_replica_meshes``), all sharing one device otherwise (CPU smoke).
+device slice (``launch.mesh.make_replica_meshes``); it fails when fewer
+than ``N*tp`` devices are visible.
 The router health-checks replicas, fails over in-flight requests and
 supports graceful drain; combine with ``--http`` for an always-on
 multi-replica service.  SIGTERM/SIGINT on the ``--http`` path triggers a
@@ -58,6 +58,15 @@ graceful drain (stop admission, finish active requests) before the
     XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --replicas 2 \\
         --shared-prefix 32 --prefill-budget 16
+
+Without ``--full`` the model is the ``reduce_for_smoke`` shrink with float32
+weights and ``max_seq`` 256 — the CPU smoke size.  ``--full`` serves the
+published widths: bf16 weights built under ``jax.jit`` straight into their
+target sharding (never float32, never gathered on one device first), with
+``--max-seq`` and ``--num-blocks`` sizing the paged pool:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --full \\
+        --max-seq 2048 --num-blocks 2049 --max-batch 8 --prefill-budget 256
 """
 
 from __future__ import annotations
@@ -70,10 +79,30 @@ import jax.numpy as jnp
 
 from repro.config.model import reduce_for_smoke
 from repro.configs import ASSIGNED, get_config
-from repro.models import init_params
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import abstract_params, init_params
 from repro.serving import InferenceEngine
+from repro.serving.engine import serving_rules
 
 DTYPES = {"bf16": jnp.bfloat16, "fp32": jnp.float32}
+
+
+def serving_config(arch: str, *, full: bool):
+    """The arch's config at published widths (``full``) or the smoke shrink."""
+    cfg = get_config(arch)
+    return cfg if full else reduce_for_smoke(cfg)
+
+
+def init_serving_params(cfg, *, seed: int, dtype, mesh=None):
+    """Random weights from ``seed``, built by one jitted program whose
+    ``out_shardings`` are the serving placement: each device materializes
+    only its own shard, in ``dtype``.  ``mesh=None`` places on one device."""
+    if mesh is None:
+        out = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    else:
+        out = serving_rules().param_shardings(cfg, mesh, abstract_params(cfg, dtype))
+    build = jax.jit(lambda key: init_params(cfg, key, dtype), out_shardings=out)
+    return build(jax.random.PRNGKey(seed))
 
 
 def main() -> None:
@@ -83,6 +112,12 @@ def main() -> None:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument(
+        "--full", action="store_true",
+        help="published widths with bf16 weights (default: the reduced smoke "
+        "config with float32 weights)",
+    )
+    ap.add_argument("--max-seq", type=int, default=256, help="longest prompt + generation")
     ap.add_argument("--cache", default="paged", choices=("paged", "dense"))
     ap.add_argument("--block-size", type=int, default=16)
     ap.add_argument("--num-blocks", type=int, default=None)
@@ -179,7 +214,8 @@ def main() -> None:
     ap.add_argument("--port", type=int, default=8080)
     args = ap.parse_args()
 
-    cfg = reduce_for_smoke(get_config(args.arch))
+    enable_compile_cache()
+    cfg = serving_config(args.arch, full=args.full)
     if cfg.is_encoder_only:
         raise SystemExit(f"{args.arch} is encoder-only; no decode serving")
     if args.replicas < 1:
@@ -191,27 +227,20 @@ def main() -> None:
 
         try:
             meshes = make_replica_meshes(args.replicas, args.tp)
-            print(
-                f"[serve] {args.replicas} replica(s) x tp={args.tp}: "
-                f"disjoint device slices"
-            )
-        except ValueError:
-            if args.tp > 1:
-                raise  # tensor parallelism genuinely needs the devices
-            print(
-                f"[serve] {args.replicas} replicas sharing "
-                f"{jax.device_count()} device(s) (host-side replication)"
-            )
+        except ValueError as e:
+            raise SystemExit(f"[serve] {e}") from e
+        print(f"[serve] {args.replicas} replica(s) x tp={args.tp}: disjoint device slices")
 
-    params = init_params(cfg, jax.random.PRNGKey(args.seed), jnp.float32)
+    param_dtype = jnp.bfloat16 if args.full else jnp.float32
 
     def build_engine(mesh):
+        params = init_serving_params(cfg, seed=args.seed, dtype=param_dtype, mesh=mesh)
         return InferenceEngine(
             cfg,
             params,
             mesh=mesh,
             max_batch=args.max_batch,
-            max_seq=256,
+            max_seq=args.max_seq,
             seed=args.seed,
             cache_kind=args.cache,
             block_size=args.block_size,

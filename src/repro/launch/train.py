@@ -24,7 +24,36 @@ from repro.config.model import reduce_for_smoke
 from repro.configs import ASSIGNED, get_config
 from repro.core import Cluster, ClusterSpec, EnergyLedger, FaultTolerantRunner
 from repro.data import make_batch_fn
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.step import init_train_state, make_train_step
+
+
+def build_training(
+    arch: str,
+    *,
+    full: bool,
+    batch: int,
+    seq: int,
+    steps: int,
+    microbatches: int = 1,
+    fp8: bool = False,
+    seed: int = 0,
+):
+    """The launcher's training set-up: ``(cfg, run, state, jitted step,
+    batch_fn)`` at published widths (``full``) or the smoke shrink."""
+    cfg = get_config(arch)
+    if not full:
+        cfg = reduce_for_smoke(cfg)
+    run = RunConfig(
+        arch=arch,
+        train=TrainConfig(global_batch=batch, seq_len=seq, warmup_steps=5, total_steps=steps),
+        parallel=ParallelConfig(num_microbatches=microbatches, remat="full"),
+        precision=PrecisionConfig(fp8=fp8),
+    )
+    state = init_train_state(cfg, run, jax.random.PRNGKey(seed))
+    step = jax.jit(make_train_step(cfg, run))
+    batch_fn = make_batch_fn(cfg, global_batch=batch, seq_len=seq, seed=seed)
+    return cfg, run, state, step, batch_fn
 
 
 def main() -> None:
@@ -43,26 +72,24 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduce_for_smoke(cfg)
+    enable_compile_cache()
+    cfg, run, state, step, batch_fn = build_training(
+        args.arch,
+        full=not args.reduced,
+        batch=args.batch,
+        seq=args.seq,
+        steps=args.steps,
+        microbatches=args.microbatches,
+        fp8=args.fp8,
+        seed=args.seed,
+    )
     print(f"[train] {cfg.name} family={cfg.family} params={cfg.param_count()/1e6:.1f}M "
           f"(reduced={args.reduced})")
-
-    run = RunConfig(
-        arch=args.arch,
-        train=TrainConfig(global_batch=args.batch, seq_len=args.seq, warmup_steps=5, total_steps=args.steps),
-        parallel=ParallelConfig(num_microbatches=args.microbatches, remat="full"),
-        precision=PrecisionConfig(fp8=args.fp8),
-    )
-    state = init_train_state(cfg, run, jax.random.PRNGKey(args.seed))
     if args.fp8:
         # 2 scale keys (x + w operand) per quantized GEMM site
         n_sites = 0 if state.fp8 is None else len(state.fp8.scale) // 2
         print(f"[train] fp8: {'ON' if state.fp8 is not None else 'unsupported family, bf16 fallback'}"
               f" ({n_sites} gemm sites, window={run.precision.fp8_amax_history})")
-    step = jax.jit(make_train_step(cfg, run))
-    batch_fn = make_batch_fn(cfg, global_batch=args.batch, seq_len=args.seq, seed=args.seed)
 
     cluster = Cluster(ClusterSpec("local", nodes_per_pod=2, num_pods=1))
     cluster.allocate([0, 1], "train")

@@ -327,8 +327,7 @@ def paged_chunk_attention(
     oracle; quantized (int8/fp8) pools quantize on the way in and take the
     dequantizing reference.  ``mesh``: tensor-parallel serving mesh — the
     Pallas kernel runs per-shard under ``shard_map`` on its local head slice
-    (XLA reference fallback when the head counts don't divide the model
-    axis).  Returns (out, new_cache) with the same keys as ``cache``.
+    (head counts that don't divide the model axis raise).  Returns (out, new_cache) with the same keys as ``cache``.
     """
     from repro.serving.kvquant import kv_quant_mode_of
 

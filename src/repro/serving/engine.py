@@ -107,8 +107,8 @@ the ``BlockAllocator``, ``PrefixIndex``, block tables and the scheduler
 plain replicated host-side logic.  Decode / chunked-prefill / verify run as
 one SPMD program with explicit ``NamedSharding`` out-specs (Pallas paged
 kernels execute per-shard under ``shard_map`` on their local head slice;
-head counts that don't divide the mesh fall back to the XLA reference
-path), and the sampler/spec-accept dispatches consume the vocab-sharded
+head counts that don't divide the mesh are refused for ``attn_impl="pallas"``),
+and the sampler/spec-accept dispatches consume the vocab-sharded
 logits directly.  TP=n greedy decode is token-identical to TP=1 (asserted
 in ``tests/test_sharded_serving.py``).
 
@@ -191,6 +191,15 @@ _block_until_ready = jax.block_until_ready
 # families prefill at exact prompt length (one trace per length).
 BUCKETED_FAMILIES = ("dense", "moe", "vlm")
 MIN_PREFILL_BUCKET = 8
+
+
+def serving_rules(parallel=None):
+    """Sharding rule table for serving: TP only by default (no FSDP —
+    decode wants weights stationary, replicated over the size-1 data axis)."""
+    from repro.config import MeshConfig, ParallelConfig
+    from repro.parallel import make_rules
+
+    return make_rules(MeshConfig(), parallel or ParallelConfig(fsdp=False, tensor_parallel=True))
 
 
 @dataclass
@@ -324,18 +333,13 @@ class InferenceEngine:
         self._rules = None
         self._cache_shardings = None
         if mesh is not None:
-            from repro.config import MeshConfig, ParallelConfig
-            from repro.parallel import make_rules
-
             missing = {"data", "model"} - set(mesh.axis_names)
             if missing:
                 raise ValueError(
                     f"serving mesh needs ('data', 'model') axes "
                     f"(launch.mesh.make_serving_mesh); got {mesh.axis_names}"
                 )
-            self._rules = make_rules(
-                MeshConfig(), parallel or ParallelConfig(fsdp=False, tensor_parallel=True)
-            )
+            self._rules = serving_rules(parallel)
             self.params = params = jax.device_put(
                 params, self._rules.param_shardings(cfg, mesh, params)
             )
@@ -346,13 +350,11 @@ class InferenceEngine:
                 and model_axis_size(mesh) > 1
                 and not kernel_shardable(mesh, cfg.num_heads, cfg.num_kv_heads)
             ):
-                warnings.warn(
+                raise ValueError(
                     f"{cfg.name}: head counts ({cfg.num_heads}/{cfg.num_kv_heads}) "
-                    f"don't divide the model axis ({model_axis_size(mesh)}); Pallas "
-                    f"paged kernels can't take a local head slice, decode runs the "
-                    f"XLA reference path",
-                    RuntimeWarning,
-                    stacklevel=2,
+                    f"don't divide the model axis ({model_axis_size(mesh)}); the "
+                    f"Pallas paged kernels can't take a local head slice "
+                    f"(use attn_impl='xla')"
                 )
 
         # DCIM bridge (paper §IV.A): each step charges chip-seconds at an
@@ -499,7 +501,7 @@ class InferenceEngine:
                     self.spill.attach_metrics(self.metrics)
             self.tbl = np.zeros((max_batch, self.max_blocks_per_seq), np.int32)
             self._tbl_dirty = True
-            self.cache = init_paged_cache(
+            make_cache = lambda: init_paged_cache(
                 cfg,
                 num_blocks,
                 block_size,
@@ -519,17 +521,22 @@ class InferenceEngine:
                     RuntimeWarning,
                     stacklevel=2,
                 )
-            self.cache = make_engine_cache(cfg, max_batch, max_seq, cache_dtype)
+            make_cache = lambda: make_engine_cache(cfg, max_batch, max_seq, cache_dtype)
 
-        if mesh is not None:
+        if mesh is None:
+            self.cache = make_cache()
+        else:
             # pools: head-sharded; tables / recurrent states: replicated.
             # Placing the cache up front (instead of letting the first jit
-            # decide) pins every later dispatch to the same layout.
+            # decide) pins every later dispatch to the same layout, and
+            # building it under jit with those out-shardings means each
+            # device only ever allocates its own slice of the pool.
+            abstract = jax.eval_shape(make_cache)
             if cache_kind == "paged":
-                self._cache_shardings = self._rules.paged_cache_shardings(cfg, mesh, self.cache)
+                self._cache_shardings = self._rules.paged_cache_shardings(cfg, mesh, abstract)
             else:
-                self._cache_shardings = self._rules.cache_shardings(cfg, mesh, self.cache)
-            self.cache = jax.device_put(self.cache, self._cache_shardings)
+                self._cache_shardings = self._rules.cache_shardings(cfg, mesh, abstract)
+            self.cache = jax.jit(make_cache, out_shardings=self._cache_shardings)()
 
         self.pos = np.full((max_batch,), 0, np.int32)  # next position per slot
         self.slots: list[Optional[Request]] = [None] * max_batch
@@ -556,14 +563,15 @@ class InferenceEngine:
             c_out = dict(out_shardings=self._cache_shardings)
         else:
             lc_out = lc3_out = c_out = {}
+        # every cache-writing jit donates the cache, so a step or an
+        # admission update touches it in place instead of holding two copies
+        # of a pool sized to fill HBM
         self._decode = jax.jit(
             lambda p, c, t, q: decode_step(cfg, p, c, t, q, attn_impl=attn_impl, mesh=mesh),
+            donate_argnums=(1,),
             **lc_out,
         )
         self._prefill = jax.jit(lambda p, b: prefill(cfg, p, b))
-        # donate the pool so admission/chunk updates touch only the request's
-        # blocks in place instead of copying the whole pool per call (donation
-        # is honored on TPU; CPU falls back to a copy)
         self._graft = jax.jit(
             lambda c, raw, blocks, n, slot: graft_prefill_into_blocks(cfg, c, raw, blocks, n, slot),
             donate_argnums=(0,),

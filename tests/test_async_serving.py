@@ -158,7 +158,8 @@ def test_preemption_under_block_pressure(setup):
     """Free slots but an exhausted block pool: admission of the
     high-priority request must evict a lower-priority one for its blocks."""
     cfg, params = setup
-    ref = ample_engine(cfg, params)
+    # EOS unreachable: lo must still hold every block when hi arrives
+    ref = ample_engine(cfg, params, eos_token=-1)
     lo_prompt, hi_prompt = [4, 4, 8, 6, 2, 11, 13, 9], [30, 31]
     ref_lo = ref.submit(lo_prompt, max_new_tokens=8)
     ref_hi = ref.submit(hi_prompt, max_new_tokens=3)
@@ -175,6 +176,7 @@ def test_preemption_under_block_pressure(setup):
         prefix_cache=False,
         prefill_budget=8,
         policy="slo",
+        eos_token=-1,
     )
     lo = eng.submit(lo_prompt, max_new_tokens=8)
     for _ in range(3):

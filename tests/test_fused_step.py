@@ -110,7 +110,9 @@ def test_fused_fewer_dispatches_and_syncs():
     cfg, params = _make("olmo-1b")
     stats = {}
     for fused in (False, True):
-        eng = _engine(cfg, params, fused=fused)
+        # EOS unreachable: every request decodes its full budget, so the
+        # per-step means cover decode ticks, not just admission ticks
+        eng = _engine(cfg, params, fused=fused, eos_token=-1)
         _drain(eng)
         stats[fused] = eng.stats()
     assert stats[True]["dispatches_per_step"] < stats[False]["dispatches_per_step"]

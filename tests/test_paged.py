@@ -224,9 +224,11 @@ def test_paged_admits_more_concurrency_same_byte_budget(setup):
 
 def test_out_of_blocks_backpressure(setup):
     cfg, params = setup
-    # 4 usable blocks of 8 = 32 positions; each request needs 2 blocks
+    # 4 usable blocks of 8 = 32 positions; each request needs 2 blocks.  EOS
+    # is unreachable so the admitted pair holds its blocks through the step.
     eng = InferenceEngine(
-        cfg, params, max_batch=4, max_seq=64, cache_kind="paged", block_size=8, num_blocks=5
+        cfg, params, max_batch=4, max_seq=64, cache_kind="paged", block_size=8, num_blocks=5,
+        eos_token=-1,
     )
     reqs = [eng.submit([1 + i, 2, 3], max_new_tokens=6) for i in range(4)]
     eng.step()

@@ -77,7 +77,8 @@ def test_never_admitted_request_has_none_ttft(setup):
     the queued/active counts carry the truncation evidence into benchmark
     JSON."""
     cfg, params = setup
-    eng = InferenceEngine(cfg, params, max_batch=1, max_seq=64)
+    # EOS unreachable: the first request must still hold the slot after 2 steps
+    eng = InferenceEngine(cfg, params, max_batch=1, max_seq=64, eos_token=-1)
     first = eng.submit([1, 2, 3], max_new_tokens=30)
     starved = eng.submit([4, 5, 6], max_new_tokens=4)
     with pytest.warns(RuntimeWarning):

@@ -99,26 +99,18 @@ def test_tp2_token_identical_to_tp1(arch, window, impl, kw):
 
 
 def test_mqa_pallas_falls_back_and_matches():
-    """num_kv_heads=1 can't shard over model=2: the engine warns, the Pallas
-    path falls back to the XLA reference per-shard logic, tokens unchanged."""
+    """num_kv_heads=1 can't shard over model=2: the engine refuses the Pallas
+    kernels (no silent switch to the reference), and the XLA path serves the
+    same tokens as one device from a replicated pool."""
     cfg, _ = _make("olmo-1b")
     cfg = cfg.replace(num_kv_heads=1)
     params = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     base, _ = _run(cfg, params, attn_impl="pallas")
-    with pytest.warns(RuntimeWarning, match="head counts"):
-        eng = InferenceEngine(
-            cfg,
-            params,
-            max_batch=2,
-            max_seq=64,
-            block_size=8,
-            cache_dtype=jnp.float32,
-            mesh=make_serving_mesh(2),
-            attn_impl="pallas",
-        )
-    reqs = [eng.submit(p, max_new_tokens=6) for p in PROMPTS]
-    eng.run_until_drained()
-    assert [r.generated for r in reqs] == base
+    mesh = make_serving_mesh(2)
+    with pytest.raises(ValueError, match="head counts"):
+        InferenceEngine(cfg, params, max_batch=2, max_seq=64, mesh=mesh, attn_impl="pallas")
+    tp, eng = _run(cfg, params, mesh=mesh, attn_impl="xla")
+    assert tp == base
     # indivisible head count -> divisibility fallback replicates the pool
     assert eng.cache["k"].sharding.spec == jax.sharding.PartitionSpec(None, None, None, None, None)
 

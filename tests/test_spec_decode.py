@@ -335,7 +335,8 @@ def test_spec_headroom_enforced_at_submit():
     """Admission must reserve spec_k positions of rollback headroom."""
     cfg, params = _make("olmo-1b")
     eng = InferenceEngine(
-        cfg, params, max_batch=1, max_seq=32, block_size=8, spec_decode="ngram", spec_k=4
+        cfg, params, max_batch=1, max_seq=32, block_size=8, spec_decode="ngram", spec_k=4,
+        eos_token=-1,  # unreachable: the request must run to its full budget
     )
     with pytest.raises(ValueError, match="headroom"):
         eng.submit(list(range(2, 22)), max_new_tokens=10)  # fits only without spec
