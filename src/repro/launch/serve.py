@@ -30,8 +30,9 @@ per verify pass; ``--tp N`` shards params and the paged K/V pools over a
 ``--tp 4`` (see docs/serving.md for the tuning guide and the
 sharded-vs-replicated state matrix).  ``--metrics-json`` / ``--trace-out``
 dump the observability layer's registry snapshot and Chrome trace after the
-drain, and ``--profile`` turns on per-phase dispatch timing (see
-docs/observability.md).
+drain, and ``--profile DIR`` writes a ``jax.profiler`` trace of the serve to
+DIR: the device's named programs and the engine's ``engine.*`` /
+``async.*`` phase spans on one clock (see docs/observability.md).
 
 ``--http`` switches from the synthetic closed-loop drive to the always-on
 service: an asyncio stepping loop (``serving.async_engine``) plus a
@@ -72,6 +73,7 @@ target sharding (never float32, never gathered on one device first), with
 from __future__ import annotations
 
 import argparse
+import contextlib
 import random
 
 import jax
@@ -195,9 +197,9 @@ def main() -> None:
         "(open in chrome://tracing or ui.perfetto.dev)",
     )
     ap.add_argument(
-        "--profile", action="store_true",
-        help="bracket each jitted dispatch with block_until_ready so step "
-        "latency decomposes by phase (adds host syncs; off by default)",
+        "--profile", default=None, metavar="DIR",
+        help="write a jax.profiler trace of the serve to DIR: device programs "
+        "and the engine's phase spans on one clock (adds no host syncs)",
     )
     ap.add_argument(
         "--policy", default="slo", choices=("slo", "fcfs"),
@@ -257,7 +259,6 @@ def main() -> None:
             policy=args.policy,
             spec_decode=args.spec_decode,
             spec_k=args.spec_k,
-            profile=args.profile,
             trace_capacity=65536 if args.trace_out else 4096,
         )
 
@@ -269,6 +270,14 @@ def main() -> None:
     else:
         eng = build_engine(meshes[0])
 
+    with jax.profiler.trace(args.profile) if args.profile else contextlib.nullcontext():
+        serve(args, cfg, eng)
+    if args.profile:
+        print(f"[serve] profiler trace -> {args.profile}")
+
+
+def serve(args, cfg, eng) -> None:
+    """Serve over HTTP, or drain the synthetic request mix and report."""
     if args.http:
         import asyncio
 

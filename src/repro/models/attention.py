@@ -60,6 +60,7 @@ def attention_specs(cfg, *, cross: bool = False) -> dict:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("qkv")
 def _qkv(cfg, p, x, kv_x=None, fp8=None):
     """Project to q,k,v. kv_x: source for k/v (cross-attention).
 
@@ -94,6 +95,7 @@ def _rms_head(x, scale, eps):
     return (xf * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+@jax.named_scope("out_proj")
 def _out(cfg, p, ctx, dtype, fp8=None):
     if fp8 is not None:
         H, hd, D = p["wo"].shape
@@ -272,16 +274,15 @@ def decode_attention(
     """
     B, W = cache_pos.shape
     q, k, v = _qkv(cfg, p, x)
-    if cfg.rotary_pct > 0 and not cfg.learned_pos_embedding:
-        q = apply_rope(q, pos[:, None], rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
-        k = apply_rope(k, pos[:, None], rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
+    q, k = _rope_decode(cfg, q, k, pos)
 
     slot = pos % W  # ring-buffer slot (full cache: W >= S so slot == pos)
     b_idx = jnp.arange(B)
     # scatter write: fuses into an in-place update on the donated cache buffer
-    new_k = cache_k.at[b_idx, slot].set(k[:, 0].astype(cache_k.dtype))
-    new_v = cache_v.at[b_idx, slot].set(v[:, 0].astype(cache_v.dtype))
-    new_cache_pos = cache_pos.at[b_idx, slot].set(pos)
+    with jax.named_scope("cache_write"):
+        new_k = cache_k.at[b_idx, slot].set(k[:, 0].astype(cache_k.dtype))
+        new_v = cache_v.at[b_idx, slot].set(v[:, 0].astype(cache_v.dtype))
+        new_cache_pos = cache_pos.at[b_idx, slot].set(pos)
 
     m = make_mask(pos[:, None], new_cache_pos, causal=cfg.causal, window=cfg.sliding_window)
     m = jnp.where(new_cache_pos[:, None, :] < 0, NEG_INF, m)  # empty slots
@@ -289,6 +290,37 @@ def decode_attention(
     return _out(cfg, p, ctx, x.dtype), new_k, new_v, new_cache_pos
 
 
+def _rope_decode(cfg, q, k, pos):
+    """RoPE at each slot's one decode position (part of the ``qkv`` scope)."""
+    if cfg.rotary_pct > 0 and not cfg.learned_pos_embedding:
+        with jax.named_scope("qkv"):
+            q = apply_rope(q, pos[:, None], rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
+            k = apply_rope(k, pos[:, None], rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
+    return q, k
+
+
+@jax.named_scope("cache_write")
+def _write_kv(cache: dict, k, v, phys, off, quant_mode):
+    """Scatter new K/V rows into the paged pools at (block ``phys``, offset
+    ``off``), quantizing on the way in for int8/fp8 pools.  Returns the new
+    cache dict."""
+    new_cache = dict(cache)
+    if quant_mode is not None:
+        from repro.serving.kvquant import quantize
+
+        kq, ks = quantize(k, quant_mode)
+        vq, vs = quantize(v, quant_mode)
+        new_cache["k"] = cache["k"].at[phys, off].set(kq)
+        new_cache["v"] = cache["v"].at[phys, off].set(vq)
+        new_cache["k_scale"] = cache["k_scale"].at[phys, off].set(ks)
+        new_cache["v_scale"] = cache["v_scale"].at[phys, off].set(vs)
+    else:
+        new_cache["k"] = cache["k"].at[phys, off].set(k.astype(cache["k"].dtype))
+        new_cache["v"] = cache["v"].at[phys, off].set(v.astype(cache["v"].dtype))
+    return new_cache
+
+
+@jax.named_scope("attention")
 def paged_chunk_attention(
     cfg,
     p: dict,
@@ -331,7 +363,7 @@ def paged_chunk_attention(
     """
     from repro.serving.kvquant import kv_quant_mode_of
 
-    k_pool, v_pool = cache["k"], cache["v"]
+    k_pool = cache["k"]
     B, C, _ = x.shape
     bs = k_pool.shape[1]
     quant_mode = kv_quant_mode_of(k_pool.dtype)
@@ -340,8 +372,9 @@ def paged_chunk_attention(
     positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]  # (B, C)
     q, k, v = _qkv(cfg, p, x)
     if cfg.rotary_pct > 0 and not cfg.learned_pos_embedding:
-        q = apply_rope(q, positions, rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
-        k = apply_rope(k, positions, rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
+        with jax.named_scope("qkv"):
+            q = apply_rope(q, positions, rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
+            k = apply_rope(k, positions, rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
 
     # dead lanes may index past the table; clamp — their write goes to scratch
     idx = jnp.minimum(positions // bs, tbl_row.shape[1] - 1)
@@ -352,19 +385,7 @@ def paged_chunk_attention(
         lane = jnp.arange(C, dtype=jnp.int32)[None, :]
         phys = jnp.where(lane < widths[:, None], phys, NULL_BLOCK)
     off = positions % bs
-    new_cache = dict(cache)
-    if quantized:
-        from repro.serving.kvquant import quantize
-
-        kq, ks = quantize(k, quant_mode)
-        vq, vs = quantize(v, quant_mode)
-        new_cache["k"] = k_pool.at[phys, off].set(kq)
-        new_cache["v"] = v_pool.at[phys, off].set(vq)
-        new_cache["k_scale"] = cache["k_scale"].at[phys, off].set(ks)
-        new_cache["v_scale"] = cache["v_scale"].at[phys, off].set(vs)
-    else:
-        new_cache["k"] = k_pool.at[phys, off].set(k.astype(k_pool.dtype))
-        new_cache["v"] = v_pool.at[phys, off].set(v.astype(v_pool.dtype))
+    new_cache = _write_kv(cache, k, v, phys, off, quant_mode)
 
     if quantized:
         from repro.kernels.paged_attention_ops import paged_prefill_attention_quantized
@@ -438,33 +459,19 @@ def paged_decode_attention(
     """
     from repro.serving.kvquant import kv_quant_mode_of
 
-    k_pool, v_pool, tbl = cache["k"], cache["v"], cache["tbl"]
+    k_pool, tbl = cache["k"], cache["tbl"]
     B = x.shape[0]
     bs = k_pool.shape[1]
     quant_mode = kv_quant_mode_of(k_pool.dtype)
     quantized = quant_mode is not None
 
     q, k, v = _qkv(cfg, p, x)
-    if cfg.rotary_pct > 0 and not cfg.learned_pos_embedding:
-        q = apply_rope(q, pos[:, None], rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
-        k = apply_rope(k, pos[:, None], rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta)
+    q, k = _rope_decode(cfg, q, k, pos)
 
     b_idx = jnp.arange(B)
     phys = tbl[b_idx, pos // bs]  # physical block holding this position
     off = pos % bs
-    new_cache = dict(cache)
-    if quantized:
-        from repro.serving.kvquant import quantize
-
-        kq, ks = quantize(k[:, 0], quant_mode)
-        vq, vs = quantize(v[:, 0], quant_mode)
-        new_cache["k"] = k_pool.at[phys, off].set(kq)
-        new_cache["v"] = v_pool.at[phys, off].set(vq)
-        new_cache["k_scale"] = cache["k_scale"].at[phys, off].set(ks)
-        new_cache["v_scale"] = cache["v_scale"].at[phys, off].set(vs)
-    else:
-        new_cache["k"] = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
-        new_cache["v"] = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
+    new_cache = _write_kv(cache, k[:, 0], v[:, 0], phys, off, quant_mode)
 
     seq_lens = pos + 1
     if quantized:

@@ -36,6 +36,7 @@ def _rmsn(x, eps=1e-5):
     return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)).astype(x.dtype)
 
 
+@jax.named_scope("attention")
 def _decode_attn(cfg, p_attn, h, cache, pos, *, sh=None, attn_impl="xla", mesh=None):
     """Decode attention against either cache layout.
 

@@ -36,6 +36,7 @@ def ffn_specs(cfg, d_ff: int | None = None) -> dict:
     return specs
 
 
+@jax.named_scope("mlp")
 def ffn(cfg, p: dict, x: jax.Array, *, sh=None, fp8=None) -> jax.Array:
     """``fp8``: an ``repro.fp8.Fp8Ctx`` — routes the up/gate/down GEMMs
     through quantized matmuls (biases/activation stay in compute dtype)."""

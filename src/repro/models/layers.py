@@ -99,6 +99,7 @@ def norm_specs(cfg, d: int | None = None) -> dict:
     raise ValueError(f"unknown norm {cfg.norm!r}")
 
 
+@jax.named_scope("norm")
 def apply_norm(cfg, p: dict, x: jax.Array) -> jax.Array:
     """Normalize in fp32, cast back to input dtype (standard LM practice)."""
     dtype = x.dtype

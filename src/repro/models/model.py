@@ -71,6 +71,7 @@ def embed_input(cfg, params, batch, *, sh=None):
     return x, pos
 
 
+@jax.named_scope("head")
 def lm_logits(cfg, params, x, *, logits_dtype=jnp.float32, sh=None):
     """Final norm + output projection (tied or untied; padded-vocab mask)."""
     x = apply_norm(cfg, params["final_norm"], x)

@@ -65,9 +65,14 @@ from repro.serving.faults import ServiceUnavailable
 class StreamEvent:
     """One streamed serving event.
 
-    ``kind="token"``: ``tokens`` holds the newly emitted token ids and
+    ``kind="token"``: ``tokens`` holds the newly emitted token ids,
     ``index`` the position of ``tokens[0]`` in the request's generated
-    sequence (speculative decoding emits several tokens per event).
+    sequence (speculative decoding emits several tokens per event) and
+    ``t`` the server's stamp of the emission on the engine's clock
+    (``time.monotonic`` by default): the request's ``first_token_t`` for
+    its first token, else the step's one read at the start of its
+    ``engine.emit`` phase.  A client's receipt time minus ``t`` is the
+    delivery delay of the stream.
     ``kind="finish"``: ``reason`` is the request's finish reason —
     ``"eos"``/``"length"`` for normal completion, ``"cancelled"`` /
     ``"deadline_exceeded"`` / ``"aborted"`` for aborts, ``"error"`` for a
@@ -84,6 +89,7 @@ class StreamEvent:
     n_tokens: int = 0
     ttft_s: Optional[float] = None
     preemptions: int = 0
+    t: Optional[float] = None
 
 
 class AsyncEngine:
@@ -106,11 +112,13 @@ class AsyncEngine:
         q = self._streams.get(req.req_id)
         if q is None or self._loop is None:
             return
+        index = len(req.generated) - len(toks)
         ev = StreamEvent(
             kind="token",
             req_id=req.req_id,
             tokens=tuple(toks),
-            index=len(req.generated) - len(toks),
+            index=index,
+            t=req.first_token_t if index == 0 else self.engine.emit_t,
         )
         self._loop.call_soon_threadsafe(q.put_nowait, ev)
 
@@ -201,30 +209,17 @@ class AsyncEngine:
             while True:
                 # drain submissions on the loop thread; no step is in
                 # flight here, so engine.submit is safe
-                while self._inbox:
-                    op = self._inbox.popleft()
-                    if op[0] == "abort":
-                        _, req_id, reason = op
-                        eng.abort(req_id, reason)
-                        continue
-                    _, fut, prompt, kw = op
-                    if fut.cancelled():
-                        continue
-                    try:
-                        req = eng.submit(prompt, **kw)
-                    except Exception as e:  # validation errors -> caller
-                        fut.set_exception(e)
-                        continue
-                    q: asyncio.Queue = asyncio.Queue()
-                    self._streams[req.req_id] = q
-                    fut.set_result((req, q))
+                if self._inbox:
+                    with eng.tracer.phase("async.submit"):
+                        self._drain_inbox()
                 if eng.has_work:
                     self._idle.clear()
                     await asyncio.to_thread(eng.step)
                 else:
                     self._idle.set()
                     self._wake.clear()
-                    await self._wake.wait()
+                    with eng.tracer.phase("async.wait"):
+                        await self._wake.wait()
                     self._idle.clear()
         except asyncio.CancelledError:
             raise
@@ -233,6 +228,26 @@ class AsyncEngine:
             # then surface the error on the task
             self._fail_streams("error")
             raise
+
+    def _drain_inbox(self) -> None:
+        eng = self.engine
+        while self._inbox:
+            op = self._inbox.popleft()
+            if op[0] == "abort":
+                _, req_id, reason = op
+                eng.abort(req_id, reason)
+                continue
+            _, fut, prompt, kw = op
+            if fut.cancelled():
+                continue
+            try:
+                req = eng.submit(prompt, **kw)
+            except Exception as e:  # validation errors -> caller
+                fut.set_exception(e)
+                continue
+            q: asyncio.Queue = asyncio.Queue()
+            self._streams[req.req_id] = q
+            fut.set_result((req, q))
 
     # -- submission ------------------------------------------------------
     async def submit_stream(

@@ -119,14 +119,19 @@ fixed-bucket histograms and throughputs in counters on ``self.metrics``;
 request-lifecycle events (submit/admit/chunk/first-token/spec/finish/evict)
 record into ``self.tracer``'s bounded ring buffer, exportable as
 Chrome-trace JSON with one track per slot plus a scheduler track.
-``profile=True`` opts into ``block_until_ready``-bracketed per-phase
-dispatch timing (off by default: the hot path takes no extra host syncs),
-and an ``EnergyBridge`` charges each step's chip-seconds into the seed
-``core.telemetry.EnergyLedger``, attributed per request as joules/token.
+Each step's phases (``engine.schedule``, ``engine.dispatch.<phase>``,
+``engine.host_fetch``, ``engine.emit``, ...) are ``tracer.phase`` spans on
+the profiler's clock, and every jitted program carries a stable name
+(``jit_decode_step``, ``jit_chunk_step``, ``jit_sample``, ...), so a
+``jax.profiler`` trace lines the host's phases up with the device's
+programs at no cost when no profiler runs.  An ``EnergyBridge`` charges
+each step's chip-seconds into the seed ``core.telemetry.EnergyLedger``,
+attributed per request as joules/token.
 """
 
 from __future__ import annotations
 
+import inspect
 import itertools
 import time
 import warnings
@@ -180,17 +185,32 @@ from repro.serving.scheduler import (  # re-exported for back-compat
 from repro.serving.spec_decode import DraftModel, make_draft_config, ngram_draft
 from repro.serving.trace import SCHEDULER_TRACK, Tracer, slot_track
 
-# patchable seam for the opt-in profiler: tests monkeypatch this to assert
-# the default path never introduces a host sync (profile=False must not
-# call it at all)
-_block_until_ready = jax.block_until_ready
-
 # families whose prefill is exact under right-padding (causal attention:
 # pad positions can never influence earlier K/V or the last-real-token
 # logits).  ssm/hybrid recurrent states WOULD absorb pad tokens, so those
 # families prefill at exact prompt length (one trace per length).
 BUCKETED_FAMILIES = ("dense", "moe", "vlm")
 MIN_PREFILL_BUCKET = 8
+
+
+def _jit(name: str, fn, **kw):
+    """``jax.jit`` of ``fn`` under a stable program name: the profiler's
+    ``XLA Modules`` line reads ``jit_<name>`` instead of ``jit__lambda``."""
+
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    program.__signature__ = inspect.signature(fn)  # argument names in the HLO metadata
+    return jax.jit(program, **kw)
+
+
+def _sample(logits, temps=None, top_ks=None, key=None):
+    """The step's sampling program: argmax for an all-greedy batch (no
+    per-slot arrays passed), else ``sample_tokens``."""
+    if temps is None:
+        return jnp.argmax(logits, axis=-1)
+    return sample_tokens(logits, temps, top_ks, key)
 
 
 def serving_rules(parallel=None):
@@ -248,7 +268,6 @@ class InferenceEngine:
         metrics: Optional[MetricsRegistry] = None,
         tracer: Optional[Tracer] = None,
         trace_capacity: int = 4096,
-        profile: bool = False,
         energy: Optional[EnergyBridge] = None,
     ):
         if cfg.is_encoder_only:
@@ -287,16 +306,17 @@ class InferenceEngine:
         self.attn_impl = attn_impl
 
         # ---- observability: one injectable clock feeds every timestamp
-        # (request lifecycle, tracer, profiler), one registry collects every
+        # (request lifecycle, tracer), one registry collects every
         # counter/gauge/histogram, one bounded ring buffer records the
-        # request-lifecycle events.  All host-side scalar work — the default
-        # path adds no device syncs (profile=True opts into
-        # block_until_ready-bracketed per-phase timing).
+        # request-lifecycle events, and the step's phase spans go to the
+        # profiler.  All host-side scalar work: no device syncs.
         self._clock = clock if clock is not None else time.monotonic
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer(self._clock, trace_capacity)
-        self._profile = profile
-        self._phase_acc: dict[str, float] = {}
+        # engine-clock stamp of the step's emitted tokens (StreamEvent.t),
+        # read once at the start of its emit phase; a request's first token
+        # carries its first_token_t instead
+        self.emit_t: Optional[float] = None
         M = self.metrics
         self._c_submitted = M.counter("engine_requests_submitted_total", "requests accepted by submit()")
         self._c_admitted = M.counter("engine_requests_admitted_total", "requests admitted into a batch slot")
@@ -536,7 +556,7 @@ class InferenceEngine:
                 self._cache_shardings = self._rules.paged_cache_shardings(cfg, mesh, abstract)
             else:
                 self._cache_shardings = self._rules.cache_shardings(cfg, mesh, abstract)
-            self.cache = jax.jit(make_cache, out_shardings=self._cache_shardings)()
+            self.cache = _jit("init_cache", make_cache, out_shardings=self._cache_shardings)()
 
         self.pos = np.full((max_batch,), 0, np.int32)  # next position per slot
         self.slots: list[Optional[Request]] = [None] * max_batch
@@ -565,41 +585,48 @@ class InferenceEngine:
             lc_out = lc3_out = c_out = {}
         # every cache-writing jit donates the cache, so a step or an
         # admission update touches it in place instead of holding two copies
-        # of a pool sized to fill HBM
-        self._decode = jax.jit(
+        # of a pool sized to fill HBM.  Each program is named (``_jit``), so
+        # a profiler trace reads ``jit_decode_step``, not ``jit__lambda``.
+        self._decode = _jit(
+            "decode_step",
             lambda p, c, t, q: decode_step(cfg, p, c, t, q, attn_impl=attn_impl, mesh=mesh),
             donate_argnums=(1,),
             **lc_out,
         )
-        self._prefill = jax.jit(lambda p, b: prefill(cfg, p, b))
-        self._graft = jax.jit(
+        self._sample = _jit("sample", _sample)
+        self._prefill = _jit("prefill", lambda p, b: prefill(cfg, p, b))
+        self._graft = _jit(
+            "graft",
             lambda c, raw, blocks, n, slot: graft_prefill_into_blocks(cfg, c, raw, blocks, n, slot),
             donate_argnums=(0,),
             **(c_out if cache_kind == "paged" else {}),
         )
         if self._chunked:
-            self._chunk_step = jax.jit(
+            self._chunk_step = _jit(
+                "chunk_step",
                 lambda p, c, t, s, row: prefill_step(
                     cfg, p, c, t, s, row, attn_impl=attn_impl, mesh=mesh
                 ),
                 donate_argnums=(1,),
                 **lc_out,
             )
-            self._copy_block = jax.jit(copy_block_rows, donate_argnums=(0,), **c_out)
+            self._copy_block = _jit("copy_block", copy_block_rows, donate_argnums=(0,), **c_out)
             # spill tier data movement: the gather is dispatched at evict
             # time (the immutable result pins the rows while the pool block
             # is reused); the scatter batches every task of one restore pass
-            self._gather_rows = jax.jit(gather_block_rows)
-            self._restore_rows = jax.jit(restore_block_rows, donate_argnums=(0,), **c_out)
+            self._gather_rows = _jit("gather_rows", gather_block_rows)
+            self._restore_rows = _jit("restore_rows", restore_block_rows, donate_argnums=(0,), **c_out)
         if self.spec_mode != "off":
-            self._verify = jax.jit(
+            self._verify = _jit(
+                "verify_step",
                 lambda p, c, t, s, row: verify_step(
                     cfg, p, c, t, s, row, attn_impl=attn_impl, mesh=mesh
                 ),
                 donate_argnums=(1,),
                 **lc3_out,
             )
-            self._trunc_rows = jax.jit(
+            self._trunc_rows = _jit(
+                "truncate_rows",
                 lambda c, tbl, s, e: truncate_block_rows(c, tbl, s, e, span=spec_k + 1),
                 donate_argnums=(0,),
                 **c_out,
@@ -664,9 +691,9 @@ class InferenceEngine:
                 fm_out = dict(out_shardings=(repl, repl, repl, repl, self._cache_shardings))
             else:
                 fd_out = fm_out = {}
-            self._fused_decode = jax.jit(_fused_decode_fn, donate_argnums=(1,), **fd_out)
-            self._fused_plain = jax.jit(_make_fused_mixed(False), donate_argnums=(1,), **fm_out)
-            self._fused_spec = jax.jit(_make_fused_mixed(True), donate_argnums=(1,), **fm_out)
+            self._fused_decode = _jit("fused_decode", _fused_decode_fn, donate_argnums=(1,), **fd_out)
+            self._fused_plain = _jit("fused_step", _make_fused_mixed(False), donate_argnums=(1,), **fm_out)
+            self._fused_spec = _jit("fused_spec_step", _make_fused_mixed(True), donate_argnums=(1,), **fm_out)
         self._bucketed = cfg.family in BUCKETED_FAMILIES
         self.steps = 0
         self.tokens_out = 0
@@ -696,7 +723,7 @@ class InferenceEngine:
         self.spec_emitted = 0  # tokens emitted via the speculative path
         # dispatch/sync accounting (the fused step's raison d'être): every
         # jitted call through the _dispatch seam and every device->host sync
-        # (_host_fetch / profiled block_until_ready) increments these, so
+        # (_host_fetch) increments these, so
         # stats() can report dispatches/syncs per step for A/B comparison
         self.dispatches_total = 0
         self.host_syncs_total = 0
@@ -807,7 +834,8 @@ class InferenceEngine:
 
     def try_admit(self, req: Request, slot: int) -> bool:
         admit = self._admit_chunked if self._chunked else self._admit_blocking
-        return admit(req, slot)
+        with self.tracer.phase("engine.admit"):
+            return admit(req, slot)
 
     def preempt(self, req: Request) -> None:
         """Evict a running request: park its committed K/V in the prefix
@@ -991,38 +1019,22 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     def _dispatch(self, phase: str, fn, *args):
-        """Run one jitted dispatch, optionally profiled.
-
-        ``profile=False`` (default) is a plain call — no timing, no
-        ``block_until_ready``, zero extra host syncs on the hot path.
-        ``profile=True`` brackets the dispatch with the injectable clock and
-        a device sync so step latency decomposes by phase
-        (``engine_profile_<phase>_seconds`` histograms, and a per-step
-        breakdown in the tracer's ``step`` span args).
-
-        Every call counts one jitted dispatch (the fused-step A/B metric);
-        the profiled branch's ``block_until_ready`` additionally counts as a
-        host sync."""
+        """Run one jitted dispatch inside an ``engine.dispatch.<phase>``
+        span.  The span times the host's dispatch, not the device's work
+        (no sync); every call counts one jitted dispatch (the fused-step A/B
+        metric)."""
         self.dispatches_total += 1
-        if not self._profile:
+        with self.tracer.phase(f"engine.dispatch.{phase}"):
             return fn(*args)
-        t0 = self._clock()
-        out = fn(*args)
-        _block_until_ready(out)
-        self.host_syncs_total += 1
-        dt = self._clock() - t0
-        self.metrics.histogram(
-            f"engine_profile_{phase}_seconds", f"synced {phase} dispatch time"
-        ).observe(dt)
-        self._phase_acc[phase] = self._phase_acc.get(phase, 0.0) + dt
-        return out
 
     def _host_fetch(self, *arrays):
         """Bring device results to the host as ONE counted sync event (the
         arrays are fetched together at the jit-call seam; per-step stats
-        report the count as ``host_syncs_per_step``)."""
+        report the count as ``host_syncs_per_step``).  The
+        ``engine.host_fetch`` span times the wait for the device."""
         self.host_syncs_total += 1
-        return tuple(np.asarray(a) for a in arrays)
+        with self.tracer.phase("engine.host_fetch"):
+            return tuple(np.asarray(a) for a in arrays)
 
     def _note_admit(self, req: Request, slot: int) -> None:
         req.admit_t = self._clock()
@@ -1307,9 +1319,8 @@ class InferenceEngine:
     def _emit_first_token(self, req: Request, logits) -> None:
         self._key, sub = jax.random.split(self._key)
         self.dispatches_total += 1
-        self.host_syncs_total += 1
-        tok = int(sample_token(logits, req.temperature, sub, top_k=req.top_k))
-        self._note_first_token(req, tok)
+        (tok,) = self._host_fetch(sample_token(logits, req.temperature, sub, top_k=req.top_k))
+        self._note_first_token(req, int(tok))
 
     def _note_first_token(self, req: Request, tok: int) -> None:
         """First-token bookkeeping shared by the legacy path (which samples
@@ -1330,6 +1341,10 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     def run_chunk(self, req: Request, c: int):
         """Run one c-token context chunk; returns the chunk's last logits."""
+        with self.tracer.phase("engine.prefill_chunk"):
+            return self._run_chunk(req, c)
+
+    def _run_chunk(self, req: Request, c: int):
         ctx = req.context()
         start = req.prefill_pos
         toks = jnp.asarray(ctx[start : start + c], jnp.int32)[None]
@@ -1396,79 +1411,81 @@ class InferenceEngine:
         """
         K = self.spec_k
         V = self.cfg.padded_vocab
-        tokens = np.zeros((self.max_batch, K + 1), np.int32)
-        drafts = np.zeros((self.max_batch, K), np.int32)
-        # draft mode carries the true proposal distributions; the ngram
-        # drafter's q is a one-hot of ``drafts`` and is built on-device
-        # below instead of materializing a dense (B, K, V) host array
-        qprobs = np.zeros((self.max_batch, K, V), np.float32) if self._draft else None
-        valid = np.zeros((self.max_batch, K), bool)
-        start = np.zeros((self.max_batch,), np.int32)
-        temps = np.zeros((self.max_batch,), np.float32)
-        top_ks = np.zeros((self.max_batch,), np.int32)
-        for r in active:
-            s = r.slot
-            ctx = r.prompt + r.generated
-            kmax = self.scheduler.spec_window(r, K)
-            if self.spec_mode == "ngram":
-                d = ngram_draft(ctx, kmax)
-            else:
-                d, q = self._draft.draft(
-                    s, ctx, kmax, temperature=r.temperature, top_k=r.top_k
-                )
+        with self.tracer.phase("engine.prepare"):
+            tokens = np.zeros((self.max_batch, K + 1), np.int32)
+            drafts = np.zeros((self.max_batch, K), np.int32)
+            # draft mode carries the true proposal distributions; the ngram
+            # drafter's q is a one-hot of ``drafts`` and is built on-device
+            # below instead of materializing a dense (B, K, V) host array
+            qprobs = np.zeros((self.max_batch, K, V), np.float32) if self._draft else None
+            valid = np.zeros((self.max_batch, K), bool)
+            start = np.zeros((self.max_batch,), np.int32)
+            temps = np.zeros((self.max_batch,), np.float32)
+            top_ks = np.zeros((self.max_batch,), np.int32)
+            for r in active:
+                s = r.slot
+                ctx = r.prompt + r.generated
+                kmax = self.scheduler.spec_window(r, K)
+                if self.spec_mode == "ngram":
+                    d = ngram_draft(ctx, kmax)
+                else:
+                    d, q = self._draft.draft(
+                        s, ctx, kmax, temperature=r.temperature, top_k=r.top_k
+                    )
+                    if d:
+                        qprobs[s, : len(d)] = q
+                tokens[s, 0] = r.generated[-1]
                 if d:
-                    qprobs[s, : len(d)] = q
-            tokens[s, 0] = r.generated[-1]
-            if d:
-                tokens[s, 1 : 1 + len(d)] = d
-                drafts[s, : len(d)] = d
-                valid[s, : len(d)] = True
-            start[s] = self.pos[s]
-            temps[s] = r.temperature
-            top_ks[s] = r.top_k
-            self.spec_slot_steps += 1
-            self.spec_drafted += len(d)
-            self._c_drafted.inc(len(d))
-            r.step_work += K + 1  # verify feeds the whole window per slot
-            self.verify_tokens += K + 1  # fed window: last committed + K lanes
+                    tokens[s, 1 : 1 + len(d)] = d
+                    drafts[s, : len(d)] = d
+                    valid[s, : len(d)] = True
+                start[s] = self.pos[s]
+                temps[s] = r.temperature
+                top_ks[s] = r.top_k
+                self.spec_slot_steps += 1
+                self.spec_drafted += len(d)
+                self._c_drafted.inc(len(d))
+                r.step_work += K + 1  # verify feeds the whole window per slot
+                self.verify_tokens += K + 1  # fed window: last committed + K lanes
+            verify_args = (jnp.asarray(tokens), jnp.asarray(start), jnp.asarray(self.tbl))
         logits, self.cache = self._dispatch(
-            "verify",
-            self._verify,
-            self.params,
-            self.cache,
-            jnp.asarray(tokens),
-            jnp.asarray(start),
-            jnp.asarray(self.tbl),
+            "verify", self._verify, self.params, self.cache, *verify_args
         )
         self.steps += 1
         self.spec_steps += 1
-        self._key, sub = jax.random.split(self._key)
-        t_sample = self._clock() if self._profile else 0.0
-        drafts_j = jnp.asarray(drafts)
-        q_j = (
-            jnp.asarray(qprobs)
-            if qprobs is not None
-            else jax.nn.one_hot(drafts_j, V, dtype=jnp.float32)
-        )
-        n_acc, final = spec_accept(
-            logits,
-            drafts_j,
-            q_j,
-            jnp.asarray(valid),
-            jnp.asarray(temps),
-            jnp.asarray(top_ks),
-            sub,
-        )
-        # np.asarray forces the host sync, so the sample phase needs no
-        # extra block_until_ready
-        self.dispatches_total += 1  # the jitted spec_accept call above
+        with self.tracer.phase("engine.prepare"):
+            self._key, sub = jax.random.split(self._key)
+            drafts_j = jnp.asarray(drafts)
+            q_j = (
+                jnp.asarray(qprobs)
+                if qprobs is not None
+                else jax.nn.one_hot(drafts_j, V, dtype=jnp.float32)
+            )
+            accept_args = (
+                logits, drafts_j, q_j, jnp.asarray(valid), jnp.asarray(temps),
+                jnp.asarray(top_ks), sub,
+            )
+        n_acc, final = self._dispatch("sample", spec_accept, *accept_args)
         n_acc, final = self._host_fetch(n_acc, final)
-        if self._profile:
-            dt = self._clock() - t_sample
-            self.metrics.histogram(
-                "engine_profile_sample_seconds", "synced sample dispatch time"
-            ).observe(dt)
-            self._phase_acc["sample"] = self._phase_acc.get("sample", 0.0) + dt
+        with self.tracer.phase("engine.emit"):
+            self.emit_t = self._clock()
+            produced, t_start, t_end = self._spec_emit(active, n_acc, final, drafts, valid, start)
+        if np.any(t_end > t_start):
+            # one whole-batch dispatch rolls back every slot's tail
+            self.cache = self._dispatch(
+                "rollback",
+                self._trunc_rows,
+                self.cache,
+                jnp.asarray(self.tbl),
+                jnp.asarray(t_start),
+                jnp.asarray(t_end),
+            )
+        return produced
+
+    def _spec_emit(self, active, n_acc, final, drafts, valid, start):
+        """Commit each slot's accepted window, stream it, and mark rejected
+        tails for rollback; returns (produced, t_start, t_end)."""
+        K = self.spec_k
         produced = 0
         t_start = np.zeros((self.max_batch,), np.int32)
         t_end = np.zeros((self.max_batch,), np.int32)  # end <= start: no-op slot
@@ -1518,17 +1535,7 @@ class InferenceEngine:
                     tokens=int(K + 1 - cut),
                 )
             self._reclaim_window_blocks(r)
-        if np.any(t_end > t_start):
-            # one whole-batch dispatch rolls back every slot's tail
-            self.cache = self._dispatch(
-                "rollback",
-                self._trunc_rows,
-                self.cache,
-                jnp.asarray(self.tbl),
-                jnp.asarray(t_start),
-                jnp.asarray(t_end),
-            )
-        return produced
+        return produced, t_start, t_end
 
     # ------------------------------------------------------------------
     def _finish_if_done(self, req: Request) -> None:
@@ -1658,18 +1665,24 @@ class InferenceEngine:
         step).  Token sequences are unchanged; per-request step counts can
         shift by one."""
         spec = self.spec_mode != "off"
-        plan = self.scheduler.plan(spec=spec)
-        self.peak_active = max(self.peak_active, sum(r is not None for r in self.slots))
-        if not plan.rows:
-            return 0
-        if not plan.chunk_rows and not spec:
-            return self._fused_decode_tick([pr.req for pr in plan.rows])
-        return self._fused_mixed_tick(plan)
+        with self.tracer.phase("engine.plan"):
+            self._enforce_deadlines()
+            plan = self.scheduler.plan(spec=spec)
+            self.peak_active = max(self.peak_active, sum(r is not None for r in self.slots))
+            if not plan.rows:
+                return 0
+            decode_only = not plan.chunk_rows and not spec
+            if decode_only:
+                active = [pr.req for pr in plan.rows]
+                args = self._pack_fused_decode(active)
+            else:
+                packed = self._pack_fused_mixed(plan)
+        if decode_only:
+            return self._fused_decode_tick(active, args)
+        return self._fused_mixed_tick(plan, packed)
 
-    def _fused_decode_tick(self, active: list[Request]) -> int:
-        """All rows are single-token decodes: one dispatch through the
-        fused decode graph (``decode_step`` + in-graph ``sample_tokens``)."""
-        self._sync_tables()
+    def _pack_fused_decode(self, active: list[Request]) -> tuple:
+        """The fused decode graph's inputs after (params, cache)."""
         B = self.max_batch
         tokens = np.zeros((B, 1), np.int32)
         temps = np.zeros((B,), np.float32)
@@ -1682,11 +1695,7 @@ class InferenceEngine:
             room[r.slot] = r.max_new_tokens - len(r.generated)
             r.step_work += 1
         self._key, sub = jax.random.split(self._key)
-        toks, done, self.cache = self._dispatch(
-            "fused_decode",
-            self._fused_decode,
-            self.params,
-            self.cache,
+        return (
             jnp.asarray(tokens),
             jnp.asarray(self.pos, jnp.int32),
             jnp.asarray(temps),
@@ -1694,29 +1703,41 @@ class InferenceEngine:
             jnp.asarray(room),
             sub,
         )
+
+    def _fused_decode_tick(self, active: list[Request], args: tuple) -> int:
+        """All rows are single-token decodes: one dispatch through the
+        fused decode graph (``decode_step`` + in-graph ``sample_tokens``)."""
+        with self.tracer.phase("engine.sync_tables"):
+            self._sync_tables()
+        toks, done, self.cache = self._dispatch(
+            "fused_decode", self._fused_decode, self.params, self.cache, *args
+        )
         toks_h, _done_h = self._host_fetch(toks, done)
         self.steps += 1
         produced = 0
-        for r in active:
-            tok = int(toks_h[r.slot])
-            r.generated.append(tok)
-            self.pos[r.slot] += 1
-            produced += 1
-            self.tokens_out += 1
-            self._c_tokens.inc()
-            if self.on_token is not None:
-                self.on_token(r, [tok])
-            self._reclaim_window_blocks(r)
-            self._finish_if_done(r)
+        with self.tracer.phase("engine.emit"):
+            self.emit_t = self._clock()
+            for r in active:
+                tok = int(toks_h[r.slot])
+                r.generated.append(tok)
+                self.pos[r.slot] += 1
+                produced += 1
+                self.tokens_out += 1
+                self._c_tokens.inc()
+                if self.on_token is not None:
+                    self.on_token(r, [tok])
+                self._reclaim_window_blocks(r)
+                self._finish_if_done(r)
         return produced
 
-    def _fused_mixed_tick(self, plan) -> int:
-        """Lower a mixed ``StepPlan`` into one unified (R, W) row batch and
-        dispatch it once.  R buckets to a power of two (pad rows carry
-        width 0 and an all-null table, so their lanes scatter into the null
-        scratch block); W is the widest row — chunk widths are the
-        power-of-two binary decomposition and the verify window is
-        ``spec_k + 1``, so the (R, W) compile cache stays small."""
+    def _pack_fused_mixed(self, plan) -> tuple:
+        """Lower a mixed ``StepPlan`` into one unified (R, W) row batch.  R
+        buckets to a power of two (pad rows carry width 0 and an all-null
+        table, so their lanes scatter into the null scratch block); W is the
+        widest row — chunk widths are the power-of-two binary decomposition
+        and the verify window is ``spec_k + 1``, so the (R, W) compile cache
+        stays small.  Returns (program, its inputs after (params, cache),
+        drafts, valid, start, has_verify)."""
         K = self.spec_k
         rows = plan.rows
         R0 = len(rows)
@@ -1795,11 +1816,7 @@ class InferenceEngine:
                     room[i] = max(r.max_new_tokens - len(r.generated), 1)
         self._key, sub = jax.random.split(self._key)
         fn = self._fused_spec if has_verify else self._fused_plain
-        final, n_acc, cut, done, self.cache = self._dispatch(
-            "fused_step",
-            fn,
-            self.params,
-            self.cache,
+        args = (
             jnp.asarray(tokens),
             jnp.asarray(start),
             jnp.asarray(widths),
@@ -1814,11 +1831,25 @@ class InferenceEngine:
             sub,
             jnp.asarray(qprobs) if qprobs is not None else None,
         )
+        return fn, args, drafts, valid, start, has_verify
+
+    def _fused_mixed_tick(self, plan, packed: tuple) -> int:
+        """Dispatch a packed mixed plan once, then apply each row's result."""
+        fn, args, drafts, valid, start, has_verify = packed
+        rows = plan.rows
+        final, n_acc, cut, done, self.cache = self._dispatch(
+            "fused_step", fn, self.params, self.cache, *args
+        )
         final_h, n_acc_h, cut_h, _done_h = self._host_fetch(final, n_acc, cut, done)
         if any(pr.kind != "chunk" for pr in rows):
             self.steps += 1
         if has_verify:
             self.spec_steps += 1
+        with self.tracer.phase("engine.emit"):
+            self.emit_t = self._clock()
+            return self._fused_mixed_emit(rows, final_h, n_acc_h, cut_h, drafts, valid, start)
+
+    def _fused_mixed_emit(self, rows, final_h, n_acc_h, cut_h, drafts, valid, start) -> int:
         produced = 0
         for i, pr in enumerate(rows):
             r = pr.req
@@ -1892,26 +1923,31 @@ class InferenceEngine:
     def step(self) -> int:
         """One engine iteration: one scheduling pass (admission with SLO
         preemption, then the chunked-prefill budget — see
-        ``scheduler.SchedulerCore``), then advance all decoding slots."""
-        t0 = self._clock()
-        done0 = len(self.done)
-        if self._profile:
-            self._phase_acc = {}
-        self._enforce_deadlines()
-        if self.fused:
-            produced = self._fused_step()
-            self._maybe_defrag()
-            self._note_step(t0, done0, produced)
+        ``scheduler.SchedulerCore``), then advance all decoding slots.
+        The whole step is the ``engine.step`` span."""
+        with self.tracer.phase("engine.step"):
+            t0 = self._clock()
+            done0 = len(self.done)
+            produced = self._fused_step() if self.fused else self._legacy_step()
+            with self.tracer.phase("engine.note_step"):
+                self._maybe_defrag()
+                self._note_step(t0, done0, produced)
             return produced
-        self.scheduler.schedule()
-        self.peak_active = max(self.peak_active, sum(r is not None for r in self.slots))
-        active = [r for r in self.slots if r is not None and not r.prefilling]
-        produced = 0
-        if active and self.spec_mode != "off":
+
+    def _legacy_step(self) -> int:
+        with self.tracer.phase("engine.schedule"):
+            self._enforce_deadlines()
+            self.scheduler.schedule()
+            self.peak_active = max(self.peak_active, sum(r is not None for r in self.slots))
+            active = [r for r in self.slots if r is not None and not r.prefilling]
+        if not active:
+            return 0
+        with self.tracer.phase("engine.sync_tables"):
             self._sync_tables()
-            produced = self._spec_step(active)
-        elif active:
-            self._sync_tables()
+        if self.spec_mode != "off":
+            return self._spec_step(active)
+        greedy = all(r.temperature <= 0.0 for r in active)
+        with self.tracer.phase("engine.prepare"):
             tokens = np.zeros((self.max_batch, 1), np.int32)
             temps = np.zeros((self.max_batch,), np.float32)
             top_ks = np.zeros((self.max_batch,), np.int32)
@@ -1919,29 +1955,22 @@ class InferenceEngine:
                 tokens[r.slot, 0] = r.generated[-1]
                 temps[r.slot] = r.temperature
                 top_ks[r.slot] = r.top_k
-            pos = jnp.asarray(self.pos, jnp.int32)
-            logits, self.cache = self._dispatch(
-                "decode", self._decode, self.params, self.cache, jnp.asarray(tokens), pos
-            )
-            self.steps += 1
-            # one whole-batch sampling dispatch; the all-greedy batch (the
-            # common serving default) skips the sort/categorical work.
-            # np.asarray is the host sync, so profiling adds no extra one
-            t_sample = self._clock() if self._profile else 0.0
-            self.dispatches_total += 1  # the sampling dispatch below
-            if all(r.temperature <= 0.0 for r in active):
-                (sampled,) = self._host_fetch(jnp.argmax(logits, axis=-1))
-            else:
+            args = (jnp.asarray(tokens), jnp.asarray(self.pos, jnp.int32))
+            # the all-greedy batch (the common serving default) skips the
+            # sort/categorical work: the sampling program is an argmax
+            sample_args = ()
+            if not greedy:
                 self._key, sub = jax.random.split(self._key)
-                (sampled,) = self._host_fetch(
-                    sample_tokens(logits, jnp.asarray(temps), jnp.asarray(top_ks), sub)
-                )
-            if self._profile:
-                dt = self._clock() - t_sample
-                self.metrics.histogram(
-                    "engine_profile_sample_seconds", "synced sample dispatch time"
-                ).observe(dt)
-                self._phase_acc["sample"] = self._phase_acc.get("sample", 0.0) + dt
+                sample_args = (jnp.asarray(temps), jnp.asarray(top_ks), sub)
+        logits, self.cache = self._dispatch(
+            "decode", self._decode, self.params, self.cache, *args
+        )
+        self.steps += 1
+        sampled = self._dispatch("sample", self._sample, logits, *sample_args)
+        (sampled,) = self._host_fetch(sampled)
+        produced = 0
+        with self.tracer.phase("engine.emit"):
+            self.emit_t = self._clock()
             for r in active:
                 tok = int(sampled[r.slot])
                 r.generated.append(tok)
@@ -1955,8 +1984,6 @@ class InferenceEngine:
                 if self.cache_kind == "paged":
                     self._reclaim_window_blocks(r)
                 self._finish_if_done(r)
-        self._maybe_defrag()
-        self._note_step(t0, done0, produced)
         return produced
 
     def _note_step(self, t0: float, done0: int, produced: int) -> None:
@@ -1964,10 +1991,7 @@ class InferenceEngine:
         energy attribution to the requests that did work this step."""
         dt = max(self._clock() - t0, 0.0)
         self._h_step.observe(dt)
-        span_args = {"produced": produced}
-        if self._profile and self._phase_acc:
-            span_args["phases"] = {k: round(v, 6) for k, v in self._phase_acc.items()}
-        self.tracer.span("step", t0, end=t0 + dt, track=SCHEDULER_TRACK, **span_args)
+        self.tracer.span("step", t0, end=t0 + dt, track=SCHEDULER_TRACK, produced=produced)
         self._g_queue.set(len(self.queue))
         self._g_active.set(sum(r is not None and not r.prefilling for r in self.slots))
         self._g_prefilling.set(len(self._prefilling))

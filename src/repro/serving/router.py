@@ -159,6 +159,7 @@ class Router:
         # fresh_tokens) per delivery, on_finish(req) once per request
         self.on_token = None
         self.on_finish = None
+        self.emit_t: Optional[float] = None  # the emitting replica's stamp
 
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         M = self.metrics
@@ -197,6 +198,7 @@ class Router:
             if rreq.first_token_t is None:
                 rreq.first_token_t = self._clock()
             rreq.generated.extend(fresh)
+            self.emit_t = rep.engine.emit_t
             if self.on_token is not None:
                 self.on_token(rreq, fresh)
 

@@ -24,9 +24,16 @@ Two consumption paths:
 The ring buffer (``capacity`` events, oldest dropped first, drops counted)
 bounds memory for always-on tracing; recording an event is one dataclass
 construction and a deque append — cheap enough to stay on by default, and
-entirely host-side (no device syncs: span durations on the default path
-measure *dispatch* time; enable the engine's ``profile=True`` to bracket
-dispatches with ``block_until_ready`` for device-inclusive phase times).
+entirely host-side (no device syncs: span durations measure *dispatch*
+time).
+
+Phase spans (``phase(name)``) are the engine's own step phases on the
+profiler's clock: each is a ``jax.profiler.TraceAnnotation``, so under
+``jax.profiler.trace(dir)`` (``launch/serve.py --profile DIR``) they share
+one timeline with the device's programs; with no profiler session active a
+span costs one Python object.  They never enter the ring buffer, whose
+request lifecycle they would otherwise push out.  Every phase name starts
+with ``engine.`` or ``async.`` (see docs/observability.md for the table).
 """
 
 from __future__ import annotations
@@ -37,6 +44,9 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
+PHASE_PREFIXES = ("engine.", "async.")
 SCHEDULER_TRACK = 0
 
 
@@ -110,6 +120,12 @@ class Tracer:
             TraceEvent(name, start, track, max(end - start, 0.0), req_id, args)
         )
         self.recorded += 1
+
+    @staticmethod
+    def phase(name: str) -> TraceAnnotation:
+        """A context-manager span on the profiler's clock (not recorded in
+        the ring buffer).  ``name`` starts with one of ``PHASE_PREFIXES``."""
+        return TraceAnnotation(name)
 
     # -- consumption ---------------------------------------------------
     @property
