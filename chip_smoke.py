@@ -13,7 +13,8 @@ One chip:
    tokens, five of them sharing a 256-token system prompt, ask for 32 new
    tokens each with EOS unreachable.  They drain through the chunked paged path under a
    256-token prefill budget, once per step path (legacy, fused) and
-   attention implementation (xla, pallas).  Each engine drains twice: the
+   chunk attention implementation (xla, pallas; decode attention is the
+   Pallas kernel on a TPU either way).  Each engine drains twice: the
    first drain compiles, the second (fresh prompts of the same shapes) is
    warm.  Every request must produce exactly 32 tokens and every drain must
    leave zero blocks in use.
@@ -26,8 +27,8 @@ One chip:
    reference puts the two candidate tokens within ``2 * LOGIT_TOL *
    rms(ref)`` of each other, the bound within which two engines that both
    pass step 2 may legitimately disagree.
-4. **Kernel presence.** The compiled pallas chunk and decode programs
-   contain ``tpu_custom_call``: the kernel ran, not a reference.
+4. **Kernel presence.** The compiled pallas chunk program and both decode
+   programs contain ``tpu_custom_call``: the kernel ran, not a reference.
 5. **Training.** Three bert-large steps at full width, seq 512, through
    ``launch/train.py``'s set-up; every loss finite.
 
@@ -258,7 +259,7 @@ def paged_logits(cfg, params, prompt: list[int], next_token: int, traffic: Traff
         cache = jax.jit(make_cache, out_shardings=shardings)()
     row = jnp.arange(1, nb + 1, dtype=jnp.int32)[None]
     chunk = jax.jit(lambda p, c, t, s, r: prefill_step(cfg, p, c, t, s, r, attn_impl=attn_impl, mesh=mesh))
-    dec = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q, attn_impl=attn_impl, mesh=mesh))
+    dec = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q, mesh=mesh))
     toks = jnp.asarray([prompt], jnp.int32)
     pos = 0
     while pos < len(prompt):
@@ -340,11 +341,13 @@ def serving_phase(cfg, params, traffic: Traffic, seed: int, checks: Checks, cloc
         log(f"logits {impl}: prefill err {e_pre:.6f} rms, first-decode err {e_dec:.6f} rms (tolerance {LOGIT_TOL})")
         checks.expect(e_pre <= LOGIT_TOL, f"{impl} prefill logits err {e_pre:.6f} > {LOGIT_TOL}")
         checks.expect(e_dec <= LOGIT_TOL, f"{impl} first-decode logits err {e_dec:.6f} > {LOGIT_TOL}")
-        if impl == "pallas":
-            for name, fn_args in (("chunk", chunk_fn), ("decode", dec_fn)):
+        # decode attention is the kernel on a TPU whatever ``impl`` says;
+        # ``impl`` picks the chunk path
+        for name, fn_args in (("chunk", chunk_fn), ("decode", dec_fn)):
+            if name == "decode" or impl == "pallas":
                 found = has_kernel(fn_args)
-                log(f"pallas {name} program contains tpu_custom_call: {found}")
-                checks.expect(found, f"pallas {name} program has no tpu_custom_call")
+                log(f"{impl} {name} program contains tpu_custom_call: {found}")
+                checks.expect(found, f"{impl} {name} program has no tpu_custom_call")
     log(
         f"logits pallas vs xla: prefill {rel_err(got['pallas'][0], got['xla'][0]):.6f} rms, "
         f"decode {rel_err(got['pallas'][1], got['xla'][1]):.6f} rms"

@@ -20,7 +20,6 @@ from repro.kernels.babelstream import (
 from repro.kernels.flash_attention_ops import flash_attention
 from repro.kernels.paged_attention_ops import (
     paged_attention,
-    paged_attention_quantized,
     paged_prefill_attention,
     paged_prefill_attention_quantized,
 )
@@ -32,7 +31,6 @@ __all__ = [
     "fp8_gemm",
     "paged_attention",
     "paged_attention_ops",
-    "paged_attention_quantized",
     "paged_prefill_attention",
     "paged_prefill_attention_quantized",
     "stream_add",

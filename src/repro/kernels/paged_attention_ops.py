@@ -4,12 +4,13 @@ prefill).
 Routes fp pools through the Pallas kernels (interpret mode off-TPU, chosen
 by ``utils.platform``);
 quantized (int8/fp8) pools with per-(token, head) scales fall back to the
-dequantizing jnp reference — the quantization savings are an HBM-traffic
-property, and on this CPU image both paths are emulated anyway.
+dequantizing jnp reference.  The serving path's decode attention is chosen
+in one place, ``decode_uses_kernel``: the kernel on a TPU, the oracle
+elsewhere (``paged_decode``).
 
 Dtype contract: the pool dtype selects the path, and the two must never
 mix — fp entry points raise on quantized pools (scales are required:
-``*_quantized``), and the quantized wrappers expect the exact
+``paged_decode``, ``*_quantized``), and the quantized paths expect the exact
 ``serving.kvquant`` layout (int8/e4m3 ``k``/``v`` + fp32 per-(token, head)
 ``k_scale``/``v_scale``).  The chunked-prefill wrappers serve both the
 prefill chunks and the speculative-decoding verify pass
@@ -32,6 +33,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import autotune
 from repro.kernels.paged_attention import paged_attention_bhd, paged_prefill_attention_bhd
 from repro.kernels.paged_attention_ref import paged_attention_ref, paged_prefill_attention_ref
+from repro.utils.platform import on_tpu
 
 
 def _quantized_pool(dtype) -> bool:
@@ -57,6 +59,20 @@ def kernel_shardable(mesh, num_q_heads: int, num_kv_heads: int) -> bool:
     if tp <= 1:
         return True
     return num_q_heads % tp == 0 and num_kv_heads % tp == 0
+
+
+def decode_uses_kernel(pool_dtype, mesh, num_q_heads: int, num_kv_heads: int) -> bool:
+    """The one rule for paged decode attention: the Pallas kernel on a TPU,
+    for an unquantized pool whose heads split over the mesh's model axis;
+    the jnp oracle everywhere else (the CPU, int8/fp8 pools, which keep
+    the dequantizing reference, and head counts the mesh can't split).
+    A test steers the choice by patching this module's ``on_tpu``, which
+    leaves the kernels' interpret mode to ``utils.platform``."""
+    return (
+        on_tpu()
+        and not _quantized_pool(pool_dtype)
+        and kernel_shardable(mesh, num_q_heads, num_kv_heads)
+    )
 
 
 def _tp_dispatch(mesh, kernel, q_spec, num_q_heads: int, num_kv_heads: int):
@@ -94,7 +110,7 @@ def paged_attention(
     mesh=None,
 ) -> jax.Array:
     if _quantized_pool(k_pool.dtype):
-        raise ValueError("quantized pools need scales: use paged_attention_quantized")
+        raise ValueError("quantized pools need scales: use paged_decode")
     tuned = autotune.get_config(
         k_pool.shape[3], k_pool.shape[1], block_tables.shape[1], k_pool.dtype
     )
@@ -122,6 +138,38 @@ def paged_attention(
             k_pool.shape[2],
         )
     return kernel(q, k_pool, v_pool, block_tables, seq_lens)
+
+
+def paged_decode(
+    q: jax.Array,  # (B, H, hd) current-token queries
+    k_pool: jax.Array,  # (N, bs, KV, hd), fp or int8/fp8
+    v_pool: jax.Array,
+    block_tables: jax.Array,  # (B, nb) int32
+    seq_lens: jax.Array,  # (B,) int32, >= 1
+    *,
+    k_scale: jax.Array | None = None,  # (N, bs, KV, 1) fp32 (quantized pools)
+    v_scale: jax.Array | None = None,
+    softcap: float = 0.0,
+    window: int = 0,
+    mesh=None,
+) -> jax.Array:
+    """The serving path's decode attention, kernel or oracle by
+    ``decode_uses_kernel``."""
+    if decode_uses_kernel(k_pool.dtype, mesh, q.shape[1], k_pool.shape[2]):
+        return paged_attention(
+            q, k_pool, v_pool, block_tables, seq_lens, softcap=softcap, window=window, mesh=mesh
+        )
+    return paged_attention_ref(
+        q,
+        k_pool,
+        v_pool,
+        block_tables,
+        seq_lens,
+        softcap=softcap,
+        window=window,
+        k_scale=k_scale,
+        v_scale=v_scale,
+    )
 
 
 @partial(jax.jit, static_argnames=("softcap", "window", "mesh"))
@@ -177,32 +225,6 @@ def paged_prefill_attention_quantized(
         v_pool,
         block_tables,
         start,
-        softcap=softcap,
-        window=window,
-        k_scale=k_scale,
-        v_scale=v_scale,
-    )
-
-
-@partial(jax.jit, static_argnames=("softcap", "window"))
-def paged_attention_quantized(
-    q: jax.Array,
-    k_pool: jax.Array,  # int8 (N, bs, KV, hd)
-    v_pool: jax.Array,
-    k_scale: jax.Array,  # fp32 (N, bs, KV, 1)
-    v_scale: jax.Array,
-    block_tables: jax.Array,
-    seq_lens: jax.Array,
-    *,
-    softcap: float = 0.0,
-    window: int = 0,
-) -> jax.Array:
-    return paged_attention_ref(
-        q,
-        k_pool,
-        v_pool,
-        block_tables,
-        seq_lens,
         softcap=softcap,
         window=window,
         k_scale=k_scale,

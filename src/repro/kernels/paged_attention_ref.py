@@ -2,10 +2,13 @@
 
 Gathers the K/V blocks addressed by each sequence's block table into a
 contiguous (B, nb*bs, KV, hd) view and runs exact fp32 softmax attention for
-the single query token.  This is both the allclose reference for the Pallas
-kernel and the ``attn_impl="xla"`` decode path of the paged serving engine
-(at smoke scale the gather materialization is irrelevant; on TPU the Pallas
-kernel avoids it).
+the single query token.  This is the allclose reference for the Pallas
+kernel, and the serving engine's decode attention wherever the kernel does
+not run (``paged_attention_ops.decode_uses_kernel``): on the CPU, for
+int8/fp8 pools and for head counts the mesh can't split.  It works over the
+whole table every call: on a TPU the gather and its float32 upcast of every
+table block, dead ones included, cost far more than the kernel, which reads
+only the live blocks.
 
 Optionally consumes int8 block pools with per-(token, head) fp32 scales (the
 ``serving.kvquant`` KIVI layout) — dequantization happens after the gather.

@@ -22,8 +22,8 @@ unified batch → in-graph sample/accept); ``--spill-bytes N`` adds the tiered
 KV cache — evicted prefix blocks spill to an N-byte host-RAM pool
 (``--spill-dtype cache|int8|fp8`` picks the at-rest encoding) and swap back
 on a prefix hit at ``--restore-budget`` blocks per step; ``--attn-impl
-pallas`` routes decode
-and prefill chunks through the paged-attention kernels; ``--spec-decode
+pallas`` routes prefill chunks (and speculative verify) through the paged
+chunk kernel (decode attention is the Pallas kernel on a TPU either way); ``--spec-decode
 ngram|draft`` turns on speculative decoding with ``--spec-k`` drafted tokens
 per verify pass; ``--tp N`` shards params and the paged K/V pools over a
 ``(data=1, model=N)`` mesh — the paper's 4-way Grace-Hopper node is

@@ -436,7 +436,6 @@ def paged_decode_attention(
     cache: dict,
     pos: jax.Array,
     *,
-    impl: str = "xla",
     sh=None,
     mesh=None,
 ):
@@ -450,20 +449,21 @@ def paged_decode_attention(
     The new K/V lands in block ``tbl[b, pos // bs]`` at offset ``pos % bs``.
     Inactive batch slots carry all-null block tables, so their writes hit the
     reserved null block, never a live request's memory.  Attention runs over
-    the logical view [0, pos] via the block table — ``impl="pallas"`` uses the
-    ``kernels.paged_attention`` gather kernel, ``impl="xla"`` the jnp oracle.
-    ``mesh``: tensor-parallel serving mesh for the Pallas path (see
+    the logical view [0, pos] via the block table, through
+    ``kernels.paged_attention_ops.paged_decode``: the Pallas kernel on a TPU
+    for unquantized pools, the jnp oracle elsewhere.  ``mesh``:
+    tensor-parallel serving mesh for the Pallas path (see
     ``paged_chunk_attention``).
 
     Returns (out, new_cache) with the same keys as ``cache``.
     """
+    from repro.kernels.paged_attention_ops import paged_decode
     from repro.serving.kvquant import kv_quant_mode_of
 
     k_pool, tbl = cache["k"], cache["tbl"]
     B = x.shape[0]
     bs = k_pool.shape[1]
     quant_mode = kv_quant_mode_of(k_pool.dtype)
-    quantized = quant_mode is not None
 
     q, k, v = _qkv(cfg, p, x)
     q, k = _rope_decode(cfg, q, k, pos)
@@ -473,45 +473,17 @@ def paged_decode_attention(
     off = pos % bs
     new_cache = _write_kv(cache, k[:, 0], v[:, 0], phys, off, quant_mode)
 
-    seq_lens = pos + 1
-    if quantized:
-        from repro.kernels.paged_attention_ops import paged_attention_quantized
-
-        ctx = paged_attention_quantized(
-            q[:, 0],
-            new_cache["k"],
-            new_cache["v"],
-            new_cache["k_scale"],
-            new_cache["v_scale"],
-            tbl,
-            seq_lens,
-            softcap=cfg.attn_logit_softcap,
-            window=cfg.sliding_window,
-        )
-    elif impl == "pallas":
-        from repro.kernels.paged_attention_ops import paged_attention
-
-        ctx = paged_attention(
-            q[:, 0],
-            new_cache["k"],
-            new_cache["v"],
-            tbl,
-            seq_lens,
-            softcap=cfg.attn_logit_softcap,
-            window=cfg.sliding_window,
-            mesh=mesh,
-        )
-    else:
-        from repro.kernels.paged_attention_ref import paged_attention_ref
-
-        ctx = paged_attention_ref(
-            q[:, 0],
-            new_cache["k"],
-            new_cache["v"],
-            tbl,
-            seq_lens,
-            softcap=cfg.attn_logit_softcap,
-            window=cfg.sliding_window,
-        )
+    ctx = paged_decode(
+        q[:, 0],
+        new_cache["k"],
+        new_cache["v"],
+        tbl,
+        pos + 1,
+        k_scale=new_cache.get("k_scale"),
+        v_scale=new_cache.get("v_scale"),
+        softcap=cfg.attn_logit_softcap,
+        window=cfg.sliding_window,
+        mesh=mesh,
+    )
     out = _out(cfg, p, ctx[:, None], x.dtype)
     return out, new_cache

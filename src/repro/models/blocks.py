@@ -37,7 +37,7 @@ def _rmsn(x, eps=1e-5):
 
 
 @jax.named_scope("attention")
-def _decode_attn(cfg, p_attn, h, cache, pos, *, sh=None, attn_impl="xla", mesh=None):
+def _decode_attn(cfg, p_attn, h, cache, pos, *, sh=None, mesh=None):
     """Decode attention against either cache layout.
 
     Paged caches (block pools + ``tbl`` block tables) and dense slot caches
@@ -46,7 +46,7 @@ def _decode_attn(cfg, p_attn, h, cache, pos, *, sh=None, attn_impl="xla", mesh=N
     attention-cache entries).
     """
     if "tbl" in cache:
-        return paged_decode_attention(cfg, p_attn, h, cache, pos, impl=attn_impl, sh=sh, mesh=mesh)
+        return paged_decode_attention(cfg, p_attn, h, cache, pos, sh=sh, mesh=mesh)
     a, nk, nv, npos = decode_attention(cfg, p_attn, h, cache["k"], cache["v"], cache["pos"], pos, sh=sh)
     return a, {"k": nk, "v": nv, "pos": npos}
 
@@ -116,9 +116,9 @@ def dense_block_chunk(cfg, p, x, cache, tbl_row, start, *, sh=None, attn_impl="x
     return x, new_attn
 
 
-def dense_block_decode(cfg, p, x, cache, pos, *, sh=None, attn_impl="xla", mesh=None):
+def dense_block_decode(cfg, p, x, cache, pos, *, sh=None, mesh=None):
     h = apply_norm(cfg, p["norm1"], x)
-    a, new_attn = _decode_attn(cfg, p["attn"], h, cache, pos, sh=sh, attn_impl=attn_impl, mesh=mesh)
+    a, new_attn = _decode_attn(cfg, p["attn"], h, cache, pos, sh=sh, mesh=mesh)
     if cfg.parallel_residual:
         f = ffn(cfg, p["mlp"], h, sh=sh)
         x = x + a + f
@@ -195,9 +195,9 @@ def moe_block_chunk(cfg, p, x, cache, tbl_row, start, *, sh=None, attn_impl="xla
     return x, new_attn
 
 
-def moe_block_decode(cfg, p, x, cache, pos, *, sh=None, attn_impl="xla", mesh=None):
+def moe_block_decode(cfg, p, x, cache, pos, *, sh=None, mesh=None):
     h = apply_norm(cfg, p["norm1"], x)
-    a, new_attn = _decode_attn(cfg, p["attn"], h, cache, pos, sh=sh, attn_impl=attn_impl, mesh=mesh)
+    a, new_attn = _decode_attn(cfg, p["attn"], h, cache, pos, sh=sh, mesh=mesh)
     x = x + a
     h2 = apply_norm(cfg, p["norm2"], x)
     mo, _ = moe_ffn(cfg, p["moe"], h2, sh=sh)
@@ -295,9 +295,9 @@ def hybrid_block_prefill(cfg, p, x, *, positions=None, q_chunk=0, sh=None):
     return x, {"k": k, "v": v, "conv": conv_state, "ssm": ssm_state}
 
 
-def hybrid_block_decode(cfg, p, x, cache, pos, *, sh=None, attn_impl="xla", mesh=None):
+def hybrid_block_decode(cfg, p, x, cache, pos, *, sh=None, mesh=None):
     h = apply_norm(cfg, p["norm1"], x)
-    a, new_attn = _decode_attn(cfg, p["attn"], h, cache, pos, sh=sh, attn_impl=attn_impl, mesh=mesh)
+    a, new_attn = _decode_attn(cfg, p["attn"], h, cache, pos, sh=sh, mesh=mesh)
     m, (conv_state, ssm_state) = ssm_mod.ssm_step(cfg, p["ssm"], h, cache["conv"], cache["ssm"])
     x = x + _hybrid_combine(p, a, m, x.dtype)
     x = x + ffn(cfg, p["mlp"], apply_norm(cfg, p["norm2"], x), sh=sh)

@@ -405,7 +405,7 @@ def unified_step(
 # ---------------------------------------------------------------------------
 
 
-def decode_step(cfg, params, cache, token, pos, *, sh=None, attn_impl="xla", mesh=None):
+def decode_step(cfg, params, cache, token, pos, *, sh=None, mesh=None):
     """One decode step.
 
     token: (B, 1) int32 (ignored dims for audio); pos: (B,) int32 absolute
@@ -414,10 +414,11 @@ def decode_step(cfg, params, cache, token, pos, *, sh=None, attn_impl="xla", mes
     The cache may be the dense slot layout (``models.cache.init_cache``) or
     the paged block-pool layout (``models.cache.init_paged_cache``) for
     dense/moe/hybrid families — the per-layer cache keys select the path.
-    ``attn_impl``: "xla" | "pallas" — paged decode attention backend (dense
-    slot caches always use the jnp path).  ``mesh``: tensor-parallel serving
-    mesh — the Pallas paged kernels run per-shard under ``shard_map`` on
-    their local head slice (jnp paths partition via GSPMD and ignore it).
+    Paged decode attention is chosen from the platform and the pool
+    (``kernels.paged_attention_ops.decode_uses_kernel``); dense slot caches
+    always use the jnp path.  ``mesh``: tensor-parallel serving mesh — the
+    Pallas paged kernel runs per-shard under ``shard_map`` on its local head
+    slice (jnp paths partition via GSPMD and ignore it).
     """
     if cfg.is_encoder_only:
         raise ValueError(f"{cfg.name} is encoder-only: no decode step")
@@ -430,7 +431,7 @@ def decode_step(cfg, params, cache, token, pos, *, sh=None, attn_impl="xla", mes
 
         def body(x, xs):
             p_layer, c_layer = xs
-            x, nc = step(cfg, p_layer, x, c_layer, pos, sh=sh, attn_impl=attn_impl, mesh=mesh)
+            x, nc = step(cfg, p_layer, x, c_layer, pos, sh=sh, mesh=mesh)
             return x, nc
 
     elif fam == "ssm":
@@ -444,9 +445,7 @@ def decode_step(cfg, params, cache, token, pos, *, sh=None, attn_impl="xla", mes
 
         def body(x, xs):
             p_layer, c_layer = xs
-            x, nc = B.hybrid_block_decode(
-                cfg, p_layer, x, c_layer, pos, sh=sh, attn_impl=attn_impl, mesh=mesh
-            )
+            x, nc = B.hybrid_block_decode(cfg, p_layer, x, c_layer, pos, sh=sh, mesh=mesh)
             return x, nc
 
     elif fam == "vlm":

@@ -107,7 +107,8 @@ the ``BlockAllocator``, ``PrefixIndex``, block tables and the scheduler
 plain replicated host-side logic.  Decode / chunked-prefill / verify run as
 one SPMD program with explicit ``NamedSharding`` out-specs (Pallas paged
 kernels execute per-shard under ``shard_map`` on their local head slice;
-head counts that don't divide the mesh are refused for ``attn_impl="pallas"``),
+head counts that don't divide the mesh are refused for ``attn_impl="pallas"``
+and keep the jnp decode),
 and the sampler/spec-accept dispatches consume the vocab-sharded
 logits directly.  TP=n greedy decode is token-identical to TP=1 (asserted
 in ``tests/test_sharded_serving.py``).
@@ -142,6 +143,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import live_blocks
 from repro.models import (
     decode_step,
     init_paged_cache,
@@ -298,8 +300,8 @@ class InferenceEngine:
         self.quantize_kv = quantize_kv if cache_kind == "paged" else None
         if self.quantize_kv and attn_impl == "pallas":
             warnings.warn(
-                f"{self.quantize_kv} block pools have no Pallas kernel yet; decode "
-                "runs the dequantizing jnp reference path despite attn_impl='pallas'",
+                f"{self.quantize_kv} block pools have no Pallas kernel yet; decode and "
+                "prefill chunks run the dequantizing jnp reference despite attn_impl='pallas'",
                 RuntimeWarning,
                 stacklevel=2,
             )
@@ -558,6 +560,28 @@ class InferenceEngine:
                 self._cache_shardings = self._rules.cache_shardings(cfg, mesh, abstract)
             self.cache = _jit("init_cache", make_cache, out_shardings=self._cache_shardings)()
 
+        # paged decode attention is chosen where it runs (the Pallas kernel on
+        # a TPU for an unquantized pool, the jnp oracle elsewhere); the engine
+        # records the choice and, per decode step, the K/V blocks it reads
+        # against the table the oracle walks
+        self._decode_kernel = False
+        if cache_kind == "paged":
+            from repro.kernels.paged_attention_ops import decode_uses_kernel
+            from repro.serving.kvquant import kv_storage_dtype
+
+            pool_dtype = kv_storage_dtype(self.quantize_kv) if self.quantize_kv else cache_dtype
+            self._decode_kernel = decode_uses_kernel(pool_dtype, mesh, cfg.num_heads, cfg.num_kv_heads)
+        M.gauge(
+            "engine_decode_attention_kernel",
+            "1 when paged decode attention runs the Pallas kernel, 0 for the jnp reference",
+        ).set(float(self._decode_kernel))
+        self._c_kv_blocks_read = M.counter(
+            "engine_decode_kv_blocks_read_total",
+            "K/V blocks decode attention reads for the decoding slots: the kernel's live blocks, or the whole table",
+        )
+        self._c_kv_blocks_table = M.counter(
+            "engine_decode_kv_blocks_table_total", "block-table entries of the decoding slots (slots x table width)"
+        )
         self.pos = np.full((max_batch,), 0, np.int32)  # next position per slot
         self.slots: list[Optional[Request]] = [None] * max_batch
         self.done: list[Request] = []
@@ -589,7 +613,7 @@ class InferenceEngine:
         # a profiler trace reads ``jit_decode_step``, not ``jit__lambda``.
         self._decode = _jit(
             "decode_step",
-            lambda p, c, t, q: decode_step(cfg, p, c, t, q, attn_impl=attn_impl, mesh=mesh),
+            lambda p, c, t, q: decode_step(cfg, p, c, t, q, mesh=mesh),
             donate_argnums=(1,),
             **lc_out,
         )
@@ -643,7 +667,7 @@ class InferenceEngine:
             def _fused_decode_fn(p, c, tokens, pos, temps, top_ks, room, key):
                 # pure-decode ticks keep decode_step's exact graph (bit-
                 # identical logits to the unfused engine), sampling folded in
-                logits, c = decode_step(cfg, p, c, tokens, pos, attn_impl=attn_impl, mesh=mesh)
+                logits, c = decode_step(cfg, p, c, tokens, pos, mesh=mesh)
                 toks = sample_tokens(logits, temps, top_ks, key)
                 done = (toks == eos) | (room <= 1)
                 return toks, done, c
@@ -1026,6 +1050,20 @@ class InferenceEngine:
         self.dispatches_total += 1
         with self.tracer.phase(f"engine.dispatch.{phase}"):
             return fn(*args)
+
+    def _count_decode_blocks(self, active: list[Request]) -> None:
+        """The K/V blocks a decode step over ``active`` reads, from the host's
+        own positions: the kernel's live blocks of each slot (its own rule,
+        sliding window included), or the whole table where the oracle runs."""
+        if self.cache_kind != "paged":
+            return
+        table = len(active) * self.max_blocks_per_seq
+        read = table
+        if self._decode_kernel:
+            lens = self.pos[[r.slot for r in active]] + 1
+            read = int(live_blocks(lens, self.block_size, self.cfg.sliding_window)[1].sum())
+        self._c_kv_blocks_read.inc(read)
+        self._c_kv_blocks_table.inc(table)
 
     def _host_fetch(self, *arrays):
         """Bring device results to the host as ONE counted sync event (the
@@ -1694,6 +1732,7 @@ class InferenceEngine:
             top_ks[r.slot] = r.top_k
             room[r.slot] = r.max_new_tokens - len(r.generated)
             r.step_work += 1
+        self._count_decode_blocks(active)
         self._key, sub = jax.random.split(self._key)
         return (
             jnp.asarray(tokens),
@@ -1956,6 +1995,7 @@ class InferenceEngine:
                 temps[r.slot] = r.temperature
                 top_ks[r.slot] = r.top_k
             args = (jnp.asarray(tokens), jnp.asarray(self.pos, jnp.int32))
+            self._count_decode_blocks(active)
             # the all-greedy batch (the common serving default) skips the
             # sort/categorical work: the sampling program is an argmax
             sample_args = ()

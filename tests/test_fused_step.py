@@ -87,7 +87,8 @@ FUSED_CASES = [
 
 
 @pytest.mark.parametrize("arch,window,impl,kw", FUSED_CASES)
-def test_fused_token_identical_to_legacy(arch, window, impl, kw):
+def test_fused_token_identical_to_legacy(arch, window, impl, kw, steer_decode):
+    steer_decode(impl)
     cfg, params = _make(arch, window)
     if kw.get("spec_decode") == "draft":
         # self-drafting: maximal acceptance, commit/rollback runs hot
@@ -95,6 +96,7 @@ def test_fused_token_identical_to_legacy(arch, window, impl, kw):
     runs = {}
     for fused in (False, True):
         eng = _engine(cfg, params, fused=fused, attn_impl=impl, **kw)
+        assert eng.metrics.get("engine_decode_attention_kernel").value == (impl == "pallas")
         runs[fused] = (_drain(eng), eng.stats())
         assert eng.allocator is None or eng.allocator.blocks_in_use == 0
     (base, bs), (out, fs) = runs[False], runs[True]

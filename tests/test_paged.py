@@ -4,6 +4,7 @@ concurrency-per-byte acceptance property (paged admits strictly more
 concurrent requests than dense under the same cache-byte budget)."""
 
 import dataclasses
+import importlib
 import warnings
 
 import jax
@@ -13,7 +14,7 @@ import pytest
 
 from repro.config.model import reduce_for_smoke
 from repro.configs import get_config
-from repro.kernels import paged_attention
+from repro.kernels import paged_attention, paged_attention_ops
 from repro.kernels.paged_attention_ref import paged_attention_ref
 from repro.models import decode_step, forward, init_paged_cache, init_params
 from repro.serving import BlockAllocator, InferenceEngine, OutOfBlocks, RequestState, blocks_needed
@@ -68,18 +69,33 @@ def test_blocks_needed():
 # ---------------------------------------------------------------------------
 
 KERNEL_CASES = [
-    # B, nb, bs, H, KV, hd, window, softcap, dtype
+    # B, nb, bs, H, KV, hd, window, softcap, dtype[, lens, blocks per DMA group]
     (2, 4, 8, 4, 2, 16, 0, 0.0, jnp.float32),
     (3, 3, 16, 8, 2, 32, 0, 0.0, jnp.float32),
     (2, 4, 8, 4, 4, 16, 12, 0.0, jnp.float32),  # sliding window
     (1, 2, 8, 2, 1, 64, 0, 30.0, jnp.float32),  # MQA + softcap
     (2, 4, 8, 4, 2, 16, 0, 0.0, jnp.bfloat16),
+    # lengths ending mid-group, mid-block and on a block boundary
+    (3, 8, 8, 4, 4, 16, 0, 0.0, jnp.float32, (13, 41, 24), 4),
+    # lengths ending exactly on a group boundary (4 blocks of 8 tokens)
+    (2, 8, 8, 4, 4, 16, 0, 0.0, jnp.float32, (32, 64), 4),
+    # one token with an all-null table: inactive slots beside a live one
+    (3, 4, 8, 4, 4, 16, 0, 0.0, jnp.float32, (1, 30, 1), 2),
+    # table width (7) not a multiple of the group (3)
+    (2, 7, 8, 4, 4, 16, 0, 0.0, jnp.float32, (56, 25), 3),
+    # GQA, 4 query heads per kv head, several groups
+    (2, 6, 8, 8, 2, 32, 0, 0.0, jnp.float32, (45, 17), 2),
+    # window that starts past the first groups, with softcap
+    (2, 8, 8, 4, 4, 16, 20, 30.0, jnp.float32, (61, 9), 2),
+    # bf16 pool, MHA, several groups
+    (2, 8, 16, 4, 4, 32, 0, 0.0, jnp.bfloat16, (100, 128), 2),
+    # bf16 GQA with window and softcap
+    (2, 8, 8, 8, 2, 16, 24, 20.0, jnp.bfloat16, (50, 3), 3),
 ]
 
 
-@pytest.mark.parametrize("case", KERNEL_CASES)
-def test_paged_attention_kernel_matches_oracle(case):
-    B, nb, bs, H, KV, hd, win, cap, dt = case
+def _kernel_inputs(case):
+    B, nb, bs, H, KV, hd, win, cap, dt, *rest = case
     N = 1 + B * nb
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, H, hd), dt)
@@ -88,12 +104,56 @@ def test_paged_attention_kernel_matches_oracle(case):
     # non-trivial tables: each sequence's blocks shuffled through the pool
     perm = jax.random.permutation(jax.random.PRNGKey(7), N - 1) + 1
     tbl = perm[: B * nb].reshape(B, nb).astype(jnp.int32)
-    lens = jnp.array([1 + (7 * b) % (nb * bs) for b in range(B)], jnp.int32)
-    out = paged_attention(q, kp, vp, tbl, lens, softcap=cap, window=win)
+    if rest:
+        lens = jnp.array(rest[0], jnp.int32)
+        tbl = jnp.where(lens[:, None] == 1, 0, tbl)  # one-token slots: all-null tables
+    else:
+        lens = jnp.array([1 + (7 * b) % (nb * bs) for b in range(B)], jnp.int32)
+    return q, kp, vp, tbl, lens
+
+
+def _decode_kernel(case, monkeypatch):
+    """The ops wrapper, or the kernel at the case's DMA group size."""
+    if len(case) > 9:
+        # the module, not the same-named wrapper ``repro.kernels`` re-exports
+        pa = importlib.import_module("repro.kernels.paged_attention")
+        monkeypatch.setattr(pa, "BLOCKS_PER_STEP", case[10])
+        return pa.paged_attention_bhd
+    return paged_attention
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_paged_attention_kernel_matches_oracle(case, monkeypatch):
+    win, cap, dt = case[6:9]
+    q, kp, vp, tbl, lens = _kernel_inputs(case)
+    out = _decode_kernel(case, monkeypatch)(q, kp, vp, tbl, lens, softcap=cap, window=win)
     ref = paged_attention_ref(q, kp, vp, tbl, lens, softcap=cap, window=win)
     tol = 2e-2 if dt == jnp.bfloat16 else 2e-5
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
     assert err < tol, f"{case}: err={err}"
+
+
+def test_paged_decode_kernel_never_reads_dead_blocks(monkeypatch):
+    """Every pool block that no sequence holds live -- table entries past
+    each live length, the window's dead head, the null block -- is NaN:
+    the kernel's output still equals the oracle on the clean pool, so no
+    dead block reaches the math."""
+    case = (4, 8, 8, 8, 2, 16, 0, 0.0, jnp.float32, (13, 64, 1, 33), 3)
+    q, kp, vp, tbl, lens = _kernel_inputs(case)
+    bs = case[2]
+    live = np.zeros(kp.shape[0], bool)
+    for row, n in zip(np.asarray(tbl), np.asarray(lens)):
+        live[row[: -(-int(n) // bs)]] = True
+    live[0] = False  # the one-token slot's only block is the null block
+    dead = jnp.asarray(~live)[:, None, None, None]
+    nan_k = jnp.where(dead, jnp.nan, kp)
+    nan_v = jnp.where(dead, jnp.nan, vp)
+    # the one-token slot reads its null block: give it a clean one of its own
+    tbl = tbl.at[2, 0].set(int(np.flatnonzero(live)[0]))
+    out = _decode_kernel(case, monkeypatch)(q, nan_k, nan_v, tbl, lens)
+    ref = paged_attention_ref(q, kp, vp, tbl, lens)
+    assert bool(jnp.all(jnp.isfinite(out)))
+    assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
 def test_paged_attention_int8_pools_close_to_fp():
@@ -131,7 +191,10 @@ PAGED_DECODE_CASES = [
 
 
 @pytest.mark.parametrize("arch,impl", PAGED_DECODE_CASES)
-def test_paged_decode_matches_teacher_forcing(arch, impl):
+def test_paged_decode_matches_teacher_forcing(arch, impl, steer_decode):
+    """``impl="pallas"`` steers the decode choice to the kernel (as on a
+    TPU; interpret mode here), ``"xla"`` leaves it at the CPU's oracle."""
+    steer_decode(impl)
     cfg = reduce_for_smoke(get_config(arch))
     if cfg.moe is not None:
         cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
@@ -144,12 +207,76 @@ def test_paged_decode_matches_teacher_forcing(arch, impl):
     cache = init_paged_cache(cfg, 1 + B * nb, BS, B, nb, jnp.float32)
     tbl = jnp.arange(1, 1 + B * nb, dtype=jnp.int32).reshape(B, nb)
     cache["tbl"] = jnp.broadcast_to(tbl[None], (cfg.num_layers, B, nb))
-    dec = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q, attn_impl=impl))
+    dec = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q))
     errs = []
     for t in range(S):
         lg, cache = dec(params, cache, tokens[:, t : t + 1], jnp.full((B,), t, jnp.int32))
         errs.append(float(np.max(np.abs(np.asarray(lg) - np.asarray(logits_tf[:, t])))))
     assert max(errs) < 5e-4, f"{arch}/{impl}: paged decode diverges by {max(errs)}"
+
+
+class _Mesh:
+    """Just enough of a mesh for the head-split rule: a model axis of 2."""
+
+    shape = {"data": 1, "model": 2}
+
+
+DECODE_CHOICES = [
+    # backend, pool dtype, (q heads, kv heads), mesh, kernel?
+    ("tpu", jnp.bfloat16, (16, 16), None, True),
+    ("tpu", jnp.float32, (8, 2), _Mesh(), True),
+    ("cpu", jnp.bfloat16, (16, 16), None, False),
+    ("tpu", jnp.int8, (16, 16), None, False),
+    ("tpu", jnp.float8_e4m3fn, (16, 16), None, False),
+    ("tpu", jnp.bfloat16, (8, 1), _Mesh(), False),  # one kv head over two devices
+]
+
+
+@pytest.mark.parametrize("backend,dtype,heads,mesh,kernel", DECODE_CHOICES)
+def test_decode_attention_choice(backend, dtype, heads, mesh, kernel, monkeypatch):
+    monkeypatch.setattr(paged_attention_ops, "on_tpu", lambda: backend == "tpu")
+    assert paged_attention_ops.decode_uses_kernel(dtype, mesh, *heads) is kernel
+
+
+def test_engine_records_decode_choice_and_blocks_read(setup, monkeypatch):
+    """The engine reports the decode attention it chose, and per decode step
+    the K/V blocks it reads for its decoding slots (from its own positions)
+    against their table width: the kernel's live blocks, ``ceil(len / bs)``
+    less those before a sliding window, or the whole table for the oracle."""
+    cfg, params = setup
+    kw = dict(max_batch=3, max_seq=64, block_size=8, cache_dtype=jnp.float32, eos_token=-1)
+    kernel = lambda eng: eng.metrics.get("engine_decode_attention_kernel").value
+
+    def blocks_read(eng, want):
+        for n in (3, 9, 17):
+            eng.submit(list(range(1, n + 1)), max_new_tokens=16)
+        while any(r is None or r.prefilling for r in eng.slots):
+            eng.step()
+        read = eng.metrics.get("engine_decode_kv_blocks_read_total")
+        table = eng.metrics.get("engine_decode_kv_blocks_table_total")
+        total = 0
+        for _ in range(8):
+            lens = [int(eng.pos[r.slot]) + 1 for r in eng.slots]
+            r0, t0 = read.value, table.value
+            eng.step()
+            assert read.value - r0 == sum(want(n) for n in lens)
+            assert table.value - t0 == 3 * eng.max_blocks_per_seq
+            total += read.value - r0
+        return total
+
+    monkeypatch.setattr(paged_attention_ops, "on_tpu", lambda: True)
+    assert kernel(InferenceEngine(cfg, params, quantize_kv="int8", **kw)) == 0.0
+    eng = InferenceEngine(cfg, params, **kw)
+    assert kernel(eng) == 1.0
+    live = blocks_read(eng, lambda n: -(-n // 8))
+    w = 12
+    eng = InferenceEngine(cfg.replace(sliding_window=w), params, **kw)
+    windowed = blocks_read(eng, lambda n: -(-n // 8) - max(n - w, 0) // 8)
+    assert windowed < live
+    monkeypatch.undo()
+    eng = InferenceEngine(cfg, params, **kw)
+    assert kernel(eng) == 0.0
+    blocks_read(eng, lambda n: eng.max_blocks_per_seq)
 
 
 # ---------------------------------------------------------------------------

@@ -257,12 +257,13 @@ EQUIV_CASES = [
 
 
 @pytest.mark.parametrize("arch,window,impl", EQUIV_CASES)
-def test_cached_chunked_engine_matches_baselines(arch, window, impl):
+def test_cached_chunked_engine_matches_baselines(arch, window, impl, steer_decode):
     """Prefix caching + chunked prefill must reproduce the dense-cache
     engine (fully independent prefill/decode path) and the uncached paged
     engine token-for-token under greedy sampling, with real sharing (the
     requests run back-to-back, so later prompts hit the registered prefix).
     """
+    steer_decode(impl)
     cfg, params = _make(arch, window)
     sys_prompt = [7, 3, 9, 4, 11, 2, 6, 8, 13, 5, 10, 12, 14, 15, 16, 17]
     prompts = [sys_prompt + [30 + i] for i in range(3)] + [[5, 9, 12]]

@@ -91,10 +91,13 @@ TP_CASES = [
 
 
 @pytest.mark.parametrize("arch,window,impl,kw", TP_CASES)
-def test_tp2_token_identical_to_tp1(arch, window, impl, kw):
+def test_tp2_token_identical_to_tp1(arch, window, impl, kw, steer_decode):
+    steer_decode(impl)
     cfg, params = _make(arch, window)
-    base, _ = _run(cfg, params, attn_impl=impl, **kw)
-    tp, _ = _run(cfg, params, mesh=make_serving_mesh(2), attn_impl=impl, **kw)
+    base, eng = _run(cfg, params, attn_impl=impl, **kw)
+    tp, tp_eng = _run(cfg, params, mesh=make_serving_mesh(2), attn_impl=impl, **kw)
+    for e in (eng, tp_eng):
+        assert e.metrics.get("engine_decode_attention_kernel").value == (impl == "pallas")
     assert base == tp, f"{arch}/w{window}/{impl}/{kw}: TP=2 changed greedy tokens"
 
 

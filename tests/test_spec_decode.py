@@ -231,7 +231,8 @@ SPEC_CASES = [
 
 
 @pytest.mark.parametrize("arch,window,impl,mode", SPEC_CASES)
-def test_spec_engine_matches_baseline(arch, window, impl, mode):
+def test_spec_engine_matches_baseline(arch, window, impl, mode, steer_decode):
+    steer_decode(impl)
     cfg, params = _make(arch, window)
     kw = {}
     if mode == "draft":

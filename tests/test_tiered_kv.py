@@ -81,11 +81,12 @@ TIERED_CASES = [
 
 
 @pytest.mark.parametrize("arch,window,impl,extra", TIERED_CASES)
-def test_spill_engine_token_identical_to_baselines(arch, window, impl, extra):
+def test_spill_engine_token_identical_to_baselines(arch, window, impl, extra, steer_decode):
     """The spill tier is a pure capacity extension: greedy outputs must be
     token-identical whether an evicted chain restores from host RAM (spill),
     re-prefills from scratch (drop / uncached), or was never paged at all
     (dense oracle) — including int8-at-rest and int8-pool configurations."""
+    steer_decode(impl)
     cfg, params = _make(arch, window)
     paged = dict(num_blocks=10, prefill_budget=8, **extra)  # 9 usable blocks
     if "quantize_kv" in extra:

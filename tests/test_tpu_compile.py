@@ -80,13 +80,62 @@ def _paged_shapes(one_chip, H, KV, q_shape):
     )
 
 
+# the olmo-1b.offline deployment's decode: 64 slots, a 128-block table (2048
+# tokens), 2048 usable blocks and the null block
+DECODE_BATCH, DECODE_POOL_BLOCKS = 64, 2049
+
+
 @pytest.mark.parametrize("heads", sorted(PAGED_HEADS))
 def test_paged_decode_compiles(one_chip, heads):
+    """The decode kernel at the deployment's shapes DMAs pool blocks where
+    they lie: no temporary at all, let alone a pool-sized one."""
     H, KV = PAGED_HEADS[heads]
-    shapes = _paged_shapes(one_chip, H, KV, (BATCH, H, HEAD_DIM))
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = S((DECODE_POOL_BLOCKS, BLOCK, KV, HEAD_DIM), jnp.bfloat16)
+    shapes = (
+        S((DECODE_BATCH, H, HEAD_DIM), jnp.bfloat16),
+        pool,
+        pool,
+        S((DECODE_BATCH, TABLE), jnp.int32),
+        S((DECODE_BATCH,), jnp.int32),
+    )
     compiled = _compile(lambda *a: paged_attention_bhd(*a, interpret=False), *shapes)
     assert _has_kernel(compiled)
     assert _reads_pool_in_place(compiled, shapes[1])
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_decode_step_compiles_with_kernel(one_chip, monkeypatch):
+    """The whole olmo-1b decode program at the deployment's shapes, with the
+    decode choice a TPU makes: it holds the kernel, and none of the oracle's
+    gathered K/V (``[B * nb, bs, KV, hd]``) in f32 or in the pool's dtype."""
+    import importlib
+
+    from repro.configs import get_config
+    from repro.kernels import paged_attention_ops
+    from repro.models import abstract_params, decode_step, init_paged_cache
+
+    monkeypatch.setattr(paged_attention_ops, "on_tpu", lambda: True)
+    # the module, not the same-named wrapper ``repro.kernels`` re-exports
+    kernel_module = importlib.import_module("repro.kernels.paged_attention")
+    monkeypatch.setattr(kernel_module, "resolve_interpret", lambda interpret=None: False)
+    cfg = get_config("olmo-1b")
+    place = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+    )
+    params = place(abstract_params(cfg, jnp.bfloat16))
+    cache = place(
+        jax.eval_shape(
+            lambda: init_paged_cache(cfg, DECODE_POOL_BLOCKS, BLOCK, DECODE_BATCH, TABLE, jnp.bfloat16)
+        )
+    )
+    token = jax.ShapeDtypeStruct((DECODE_BATCH, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((DECODE_BATCH,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, t, q: decode_step(cfg, p, c, t, q), donate_argnums=(1,))
+    text = step.lower(params, cache, token, pos).compile().as_text()
+    assert "tpu_custom_call" in text
+    gathered = f"[{DECODE_BATCH * TABLE},{BLOCK},{cfg.num_kv_heads},{cfg.head_dim}]"
+    assert f"f32{gathered}" not in text and f"bf16{gathered}" not in text
 
 
 @pytest.mark.parametrize("heads", sorted(PAGED_HEADS))
