@@ -7,7 +7,9 @@ Runs the cell on each seed in this one process (which alone holds the chip)
 and, after each window, reads two numbers over the same sampled requests:
 the widest reference-logit gap of a token the program served (the number
 ``correct`` compares), and the same gap for the token that the control, the
-reference computed in float8 e4m3, puts first at each position. The limit in
+configuration's reference (``bench/references/<config>.py``) computed in its
+lower precision (``CONTROL``: float8 e4m3 for a bf16 configuration), puts
+first at each position. The limit in
 ``bench/configs/<config>.json`` lies between the largest program reading
 (the lower) and the smallest control reading (the upper). Each seed's line
 gives both verdicts: ``program_correct``, and ``control_correct``, the

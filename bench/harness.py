@@ -6,7 +6,10 @@ process. The benchmark contributes the weights (``bench/weights.py``), the
 traffic (``bench/traffic/generate.py``), the client that sends requests at
 their due times and stamps every streamed token, a timer around the engine's
 ``step`` (``StepRecorder``), and the check of what was served against the
-plain reference (``bench/reference.py``).
+configuration's plain reference (``bench/references/<config>.py``, found by
+``spec.reference``). A configuration whose ``mesh`` has ``model`` > 1 runs as
+one engine over the program's ``(data=1, model=tp)`` serving mesh; on one chip
+the weights sit on the first device and the engine has no mesh.
 """
 
 from __future__ import annotations
@@ -19,11 +22,14 @@ import os
 import shutil
 import sys
 import time
+from functools import partial
 from pathlib import Path
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, spec, work
+from bench import spec, work
 from bench.record import RequestRecord, RunRecord, StepRecord, percentile, token_gaps, ttfts
 from bench.traffic.generate import FIRST_TOKEN, schedule, tenant_prompts
 
@@ -43,8 +49,6 @@ def span(name: str, on: bool):
     """A ``jax.profiler.TraceAnnotation`` in traced runs, else nothing."""
     if not on:
         return contextlib.nullcontext()
-    import jax
-
     return jax.profiler.TraceAnnotation(name)
 
 
@@ -55,8 +59,6 @@ class CompileClock:
     HIT = "/jax/compilation_cache/cache_hits"
 
     def __init__(self):
-        import jax
-
         self.seconds = 0.0
         self.count = 0
         self.hits = 0
@@ -76,8 +78,6 @@ class CompileClock:
 def check_devices(chips: int, *, require_tpu: bool = True):
     """The devices of the cell. Without a TPU, or with fewer chips than the
     cell asks for, the run ends here with no result."""
-    import jax
-
     devices = jax.devices()
     if require_tpu and devices[0].platform != "tpu":
         raise SystemExit(f"no TPU: JAX found {devices[0].platform} devices only; this benchmark never falls back")
@@ -86,43 +86,30 @@ def check_devices(chips: int, *, require_tpu: bool = True):
     return devices[:chips]
 
 
-ARCH_OF_PROGRAM = {"layernorm_nonparametric": "layernorm_np"}
-
-
-def program_config(cfg: dict):
-    """The program's registry entry at the configuration file's sizes; its
-    architecture has to be the one the file states."""
+def program_config(cfg: dict, ref):
+    """The program's registry entry at the configuration file's sizes
+    (``ref.program_sizes``); its architecture has to be the one the
+    configuration's reference states (``ref.program_fields``)."""
     from repro.configs import get_config
 
-    arch = cfg["architecture"]
-    pc = get_config(cfg["model"]).replace(
-        num_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"],
-        rope_theta=cfg["rope_theta"],
-        norm_eps=arch["norm_eps"],
-    )
-    stated = {
-        "family": arch["family"],
-        "norm": ARCH_OF_PROGRAM.get(arch["norm"]),
-        "activation": arch["activation"],
-        "tie_embeddings": arch["tie_word_embeddings"],
-        "use_bias": arch["attention_bias"],
-        "rotary_pct": 1.0,
-        "sliding_window": 0,
-        "qk_norm": False,
-        "parallel_residual": False,
-        "scale_embedding": False,
-        "attn_logit_softcap": 0.0,
-    }
-    differ = {k: (v, getattr(pc, k)) for k, v in stated.items() if getattr(pc, k) != v}
+    pc = get_config(cfg["model"]).replace(**ref.program_sizes(cfg))
+    differ = {k: (v, getattr(pc, k)) for k, v in ref.program_fields(cfg).items() if getattr(pc, k) != v}
     if differ or pc.padded_vocab != pc.vocab_size:
         raise SystemExit(f"the program's {cfg['model']} is not the stated architecture: {differ}")
     return pc
+
+
+def serving_mesh(cfg: dict, chips: int):
+    """The program's ``(data=1, model=tp)`` serving mesh over the cell's
+    chips, or None where the configuration's mesh is one chip."""
+    m = cfg["mesh"]
+    if m["data"] != 1 or m["model"] != chips:
+        raise SystemExit(f"mesh {m} does not lay the cell's {chips} chips out as (data=1, model={chips})")
+    if chips == 1:
+        return None
+    from repro.launch.mesh import make_serving_mesh
+
+    return make_serving_mesh(chips)
 
 
 class StepRecorder:
@@ -234,6 +221,8 @@ async def _consume(aeng, prompt, max_new, rec: RequestRecord) -> None:
         rec.req_id = ev.req_id
         if ev.kind == "token":
             rec.token_t.extend([now] * len(ev.tokens))
+            if ev.t is not None:
+                rec.token_delay.extend([time.monotonic() - ev.t] * len(ev.tokens))
             rec.tokens.extend(ev.tokens)
         else:
             rec.finish = ev.reason
@@ -253,8 +242,6 @@ async def _drive(aeng, recorder, phases, items, *, seconds, backlog, trace_dir, 
     loop for seconds, so neither may fall where requests arrive or stream.
     The reduction covers the ``bench_window`` span, the middle of the window
     by ``TRACE_SPAN``."""
-    import jax
-
     for phase in phases:
         recs = [RequestRecord(0.0, tuple(p), n) for p, n in phase]
         await asyncio.gather(*(_consume(aeng, r.prompt, r.target, r) for r in recs))
@@ -361,13 +348,34 @@ def ref_length(cell) -> int:
     return -(-n // 256) * 256
 
 
-def served_gaps(cell, params, sample, *, control: bool):
-    """Per request, the widest reference-logit gap of a served token (and, with
-    ``control``, of the token the fp8 control puts first)."""
-    import jax.numpy as jnp
+@partial(jax.jit, static_argnums=0)
+def _served_gaps(head, params, ref_hidden, targets):
+    """Per position: how far the served token's reference logit lies below
+    the reference's best (0 where it is the best). ``targets`` (B, S) holds the
+    token served after each position, -1 where none was served."""
+    logits = head(params, ref_hidden, None)
+    best = jnp.max(logits, axis=-1)
+    got = jnp.take_along_axis(logits, jnp.maximum(targets, 0)[..., None], axis=-1)[..., 0]
+    return jnp.where(targets >= 0, best - got, 0.0)
 
+
+@partial(jax.jit, static_argnums=(0, 1))
+def _control_gaps(head, quant, params, ref_hidden, ctl_hidden, targets):
+    """Per position: the reference gap of the token the control puts first."""
+    ref = head(params, ref_hidden, None)
+    ctl = head(params, ctl_hidden, quant)
+    first = jnp.argmax(ctl, axis=-1)
+    got = jnp.take_along_axis(ref, first[..., None], axis=-1)[..., 0]
+    return jnp.where(targets >= 0, jnp.max(ref, axis=-1) - got, 0.0)
+
+
+def served_gaps(cell, ref, params, sample, *, control: bool):
+    """Per request, the widest reference-logit gap of a served token (and, with
+    ``control``, of the token the configuration's lower-precision control,
+    ``ref.CONTROL``, puts first). ``ref`` is the configuration's reference
+    module (``spec.reference``)."""
     S = ref_length(cell)
-    key = reference.cfg_key(cell.config)
+    cfg = cell.config
     gaps, ctl = [], []
     for g in range(0, len(sample), REF_GROUP):
         group = sample[g : g + REF_GROUP]
@@ -379,12 +387,12 @@ def served_gaps(cell, params, sample, *, control: bool):
             toks[i, : len(seq) - 1] = seq[:-1]
             tgt[i, n_p - 1 : len(seq) - 1] = r.tokens
         t, y = jnp.asarray(toks), jnp.asarray(tgt)
-        h = reference.hidden(key, None, params, t)
-        gap = np.asarray(reference.served_gaps(params, h, y))
+        h = ref.hidden(cfg, None, params, t)
+        gap = np.asarray(_served_gaps(ref.head, params, h, y))
         gaps += [float(gap[i].max()) for i in range(len(group))]
         if control:
-            hc = reference.hidden(key, "fp8", params, t)
-            c = np.asarray(reference.control_gaps(params, h, hc, y))
+            hc = ref.hidden(cfg, ref.CONTROL, params, t)
+            c = np.asarray(_control_gaps(ref.head, ref.CONTROL, params, h, hc, y))
             ctl += [float(c[i].max()) for i in range(len(group))]
             del hc
         del h
@@ -416,20 +424,19 @@ def run(
     require_tpu: bool = True,
     peaks_table: dict | None = None,
     control: bool = False,
-    keep_requests: bool = False,
+    keep_record: bool = False,
+    root: Path = spec.ROOT,
 ) -> dict:
-    """One run; returns the result object. With ``control`` the fp8 control
-    takes the program's place in the comparison: ``correct`` and ``checks``
-    are the control's, and ``_calibration`` holds both sides' readings.
-    ``keep_requests`` adds the request records under ``_requests``."""
-    import jax
-
-    cell = cell or spec.load_cell(workload)
-    if cell.chips != 1:
-        raise SystemExit(f"{workload} asks for {cell.chips} chips; this harness drives one")
+    """One run; returns the result object. With ``control`` the
+    configuration's lower-precision control takes the program's place in the
+    comparison: ``correct`` and ``checks`` are the control's, and
+    ``_calibration`` holds both sides' readings. ``keep_record`` adds the
+    run's ``RunRecord`` under ``_record``. ``root`` holds ``BENCHMARK.json``
+    and the files it names."""
+    cell = cell or spec.load_cell(workload, root)
     devices = check_devices(cell.chips, require_tpu=require_tpu)
     kind = devices[0].device_kind
-    peak = (peaks_table or {}).get(kind) or spec.peaks(kind)
+    peak = (peaks_table or {}).get(kind) or spec.peaks(kind, cell.root)
     clock = CompileClock()
     cache_dir = jax.config.jax_compilation_cache_dir
     from repro.serving import InferenceEngine
@@ -437,15 +444,23 @@ def run(
 
     t_import = time.perf_counter()
     cfg = cell.config
-    pcfg = program_config(cfg)
+    ref = spec.reference(cell)
+    pcfg = program_config(cfg, ref)
     from repro.models import abstract_params
 
     from bench import weights
 
-    abstract = abstract_params(pcfg, jax.numpy.bfloat16)
-    weights.check_layout(cfg, abstract)
-    shardings = jax.tree.map(lambda _: jax.sharding.SingleDeviceSharding(devices[0]), abstract)
-    params = weights.make(cfg, seed, shardings)
+    abstract = abstract_params(pcfg, jnp.bfloat16)
+    layout = ref.layout(cfg)
+    weights.check_layout(layout, abstract)
+    mesh = serving_mesh(cfg, cell.chips)
+    if mesh is None:
+        shardings = jax.tree.map(lambda _: jax.sharding.SingleDeviceSharding(devices[0]), abstract)
+    else:
+        from repro.serving.engine import serving_rules
+
+        shardings = serving_rules().param_shardings(pcfg, mesh, abstract)
+    params = weights.make(layout, seed, shardings)
     jax.block_until_ready(params)
     t_weights = time.perf_counter()
 
@@ -458,9 +473,10 @@ def run(
         block_size=dep["block_size"],
         num_blocks=dep["num_blocks"],
         prefill_budget=dep["prefill_budget"],
-        cache_dtype={"bfloat16": jax.numpy.bfloat16, "float32": jax.numpy.float32}[dep["cache_dtype"]],
+        cache_dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[dep["cache_dtype"]],
         eos_token=-1,  # unreachable: every request emits the length its traffic drew
         seed=seed & 0x7FFFFFFF,
+        mesh=mesh,
     )
     recorder = StepRecorder(eng, detail=trace, annotate=trace)
     aeng = AsyncEngine(eng)
@@ -524,11 +540,15 @@ def run(
         trace_span=marks.get("trace_span"),
     )
     if trace:
-        from bench import trace_reduce
+        from bench import program_trace, trace_reduce
 
         t_red = time.perf_counter()
-        rec.trace = trace_reduce.reduce(trace_reduce.read_xplane(trace_reduce.find_xplane(trace_dir)))
+        path = trace_reduce.find_xplane(trace_dir)
+        rec.trace = trace_reduce.reduce(trace_reduce.read_xplane(path))
+        rec.program = program_trace.read(path, host_names=trace_reduce.HOST_SPANS + (trace_reduce.WINDOW_SPAN,))
         shutil.rmtree(trace_dir, ignore_errors=True)
+        for key, value in program_trace.readings(rec.program).items():
+            log(f"program {key}: {value}")
         log(
             f"trace: busy {rec.trace['busy_s']:.6f} s of {rec.trace['window_s']:.6f} s, idle by host span "
             f"{rec.trace['idle_by_span']}; read and reduced in {time.perf_counter() - t_red:.3f} s"
@@ -540,7 +560,7 @@ def run(
     metric_defs = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in metric_defs:
-        v = spec.metric_reader(m["name"])(rec)
+        v = spec.metric_reader(m["name"], cell.root)(rec)
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     lateness = [r.submit_t - r.due_t for r in requests if r.submit_t is not None]
@@ -563,7 +583,7 @@ def run(
     gc.collect()
     sample = choose_sample(requests, seed)
     t_ref = time.perf_counter()
-    gaps, ctl = served_gaps(cell, params, sample, control=control)
+    gaps, ctl = served_gaps(cell, ref, params, sample, control=control)
     checks = checks_of(cell, requests, ctl if control else gaps)
     log(f"reference over {len(sample)} requests, {sum(len(r.tokens) for r in sample)} served tokens, {time.perf_counter() - t_ref:.3f} s")
 
@@ -584,8 +604,8 @@ def run(
         result["device"]["window_s"] = rec.trace["window_s"]
         result["breakdown"] = {"device_ops": rec.trace["device_ops"], "idle_gaps": rec.trace["idle_gaps"]}
     result["checks"] = checks
-    if keep_requests:
-        result["_requests"] = requests
+    if keep_record:
+        result["_record"] = rec
     if control:
         result["_calibration"] = {
             "gaps": gaps,

@@ -1,4 +1,3 @@
-#!/usr/bin/env python3
 """Read the program's own spans and named device programs out of a profiler
 trace (``.xplane.pb``), beside ``bench/trace_reduce.py``'s reduction.
 
@@ -18,33 +17,24 @@ trace (``.xplane.pb``), beside ``bench/trace_reduce.py``'s reduction.
 
 The readings built on it: device time by program, the decode program's
 device time by scope, the traced window's idle put down to the innermost
-program span open on the host, and three per-layer quantities
-(``decode_attn_roofline``, ``host_step_ms``, ``token_delivery_p95_s``).
-
-Run as a script, it drives one traced run of a cell through
-``bench/harness.py`` and prints these readings after the harness's result:
-
-    python3 bench/program_trace.py --workload olmo-1b.offline --seed 7 --seconds 51
-
-The harness deletes its trace once reduced and keeps no emission stamps,
-so the script reads the trace and the stream from outside: a wrapper
-around ``trace_reduce.find_xplane`` reads the same file before it goes, one
-around ``AsyncEngine.submit_stream`` stamps each token's receipt, and the
-run's ``RunRecord`` is kept as the harness builds it. Once the harness keeps
-the trace's readings and the stamps itself, ``observed``, ``traced_run`` and
-``main`` go, and ``read`` with the readings stay.
+program span open on the host, and the per-layer quantities
+(``decode_attn_roofline``, ``host_step_ms``, ``token_delivery_p95_s``) that
+readers in ``bench/metrics/`` take from ``RunRecord.program``. A traced
+``harness.run`` reads its trace here before deleting it, and logs
+``readings``.
 """
 
 from __future__ import annotations
 
 import bisect
-import contextlib
 import re
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
+
+from bench import work
+from bench.record import percentile
 
 PROGRAM_PREFIXES = ("engine.", "async.")
 SCOPES = ("attention", "mlp", "qkv", "out_proj", "norm", "head", "cache_write")
@@ -585,20 +575,9 @@ def host_step_ms(pt: ProgramTrace, rec) -> Optional[float]:
     return float(np.mean(vals)) if vals else None
 
 
-def decode_attn_least_s(shapes, keys: list, peak: dict, chips: int = 1) -> float:
-    """Least time of one decode step's attention at the chip's peaks: the
-    decoding slots' live K/V (every layer, bf16) over HBM bandwidth, or
-    4 * heads * head_dim FLOPs per attended key over the bf16 peak,
-    whichever is longer."""
-    n = sum(keys)
-    by_bytes = shapes.kv_bytes_per_token * n / (chips * peak["hbm_bytes_per_s"])
-    by_flops = shapes.layers * 4.0 * shapes.heads * shapes.head_dim * n / (chips * peak["bf16_flops_per_s"])
-    return max(by_bytes, by_flops)
-
-
 def decode_attn_roofline(pt: ProgramTrace, rec) -> Optional[float]:
     """Least time of the decode attention of the traced span's decode-only
-    steps (``decode_attn_least_s``) over the device time of the leaf ops
+    steps (``work.decode_attn_least_s``) over the device time of the leaf ops
     scoped ``attention`` (their own op_name or, without one, the ops they
     read) in the ``jit_decode_step`` executions that start inside those
     steps' ``engine.step`` spans, in %."""
@@ -617,7 +596,7 @@ def decode_attn_roofline(pt: ProgramTrace, rec) -> Optional[float]:
             t += sum(attn[dev].get(i, 0.0) for i in range(lo, hi) if runs[i][2] == DECODE_PROGRAM)
         if t <= 0:
             continue
-        least += decode_attn_least_s(rec.shapes, st.decode_keys, rec.peak, rec.chips)
+        least += work.decode_attn_least_s(rec.shapes, st.decode_keys, rec.peak, rec.chips)
         spent += t / len(pt.ops) / 1e9
     return 100.0 * least / spent if spent > 0 else None
 
@@ -626,111 +605,27 @@ def token_delivery_p95_s(deliveries: list, t_open: float, t_close: float) -> Opt
     """95th percentile, over the tokens received in ``[t_open, t_close)``, of
     the client's receipt minus the program's emission stamp.
     ``deliveries``: ``(receipt, delay)`` pairs on the host clock."""
-    from bench.record import percentile
-
     return percentile([d for t, d in deliveries if t_open <= t < t_close], 95)
 
 
-# -- one traced run of a cell ------------------------------------------------
+# -- every reading of one traced run ----------------------------------------
 
 
-def readings(pt: Optional[ProgramTrace], rec, deliveries: list) -> dict:
-    """Every reading of one run; those of the trace are left out without one
-    (or without a device plane in it)."""
-    out = {"per_layer": {"token_delivery_p95_s": token_delivery_p95_s(deliveries, rec.t_open, rec.t_close)}}
-    if pt is None or not pt.ops:
-        return out
+def readings(pt: ProgramTrace) -> dict:
+    """The readings of a traced run beside its per-layer metrics, for its
+    log: skew, the traced window, device time by program, the decode
+    program's time by scope and its top ops, and the idle split by program
+    span. Empty without a device plane in the trace."""
+    if not pt.ops:
+        return {}
     lo, hi = window(pt)
     split = idle_split(pt, lo, hi)
-    out.update(
-        skew_ms=pt.skew_ns / 1e6,
-        window_s=(hi - lo) / 1e9,
-        programs_s=by_program(pt, lo, hi),
-        decode_by_scope_s=by_scope(pt, lo, hi),
-        decode_top_ops=top_ops(pt, lo, hi),
-        idle=split,
-        idle_in_engine_step_named_share=named_child_share(split["inside"]),
-    )
-    out["per_layer"].update(decode_attn_roofline=decode_attn_roofline(pt, rec), host_step_ms=host_step_ms(pt, rec))
-    return out
-
-
-@contextlib.contextmanager
-def observed(kept: dict):
-    """Wrap, for one ``harness.run``: ``trace_reduce.find_xplane`` (the trace
-    is read into ``kept["trace"]`` before the harness deletes it), the
-    harness's ``RunRecord`` (kept as ``kept["rec"]``) and
-    ``AsyncEngine.submit_stream`` (each token's receipt on the host clock and
-    its delay after the program's stamp, in ``kept["deliveries"]``)."""
-    from bench import harness, trace_reduce
-    from repro.serving.async_engine import AsyncEngine
-
-    kept.setdefault("deliveries", [])
-    find, record, stream = trace_reduce.find_xplane, harness.RunRecord, AsyncEngine.submit_stream
-
-    def find_and_read(trace_dir):
-        path = find(trace_dir)
-        kept["trace"] = read(path, host_names=trace_reduce.HOST_SPANS + (trace_reduce.WINDOW_SPAN,))
-        return path
-
-    @dataclass
-    class KeptRecord(record):
-        def __post_init__(self):
-            kept["rec"] = self
-
-    async def stamped(self, *a, **kw):
-        async for ev in stream(self, *a, **kw):
-            if ev.kind == "token" and ev.t is not None:
-                kept["deliveries"].append((time.perf_counter(), time.monotonic() - ev.t))
-            yield ev
-
-    trace_reduce.find_xplane, harness.RunRecord, AsyncEngine.submit_stream = find_and_read, KeptRecord, stamped
-    try:
-        yield kept
-    finally:
-        trace_reduce.find_xplane, harness.RunRecord, AsyncEngine.submit_stream = find, record, stream
-
-
-def traced_run(workload: str, *, seed: int, seconds: float, trace: bool = True, **run_kw) -> tuple:
-    """One ``harness.run`` of a cell; returns its result and ``readings``."""
-    from bench import harness
-
-    kept: dict = {}
-    with observed(kept):
-        result = harness.run(workload, seed=seed, seconds=seconds, trace=trace, **run_kw)
-    return result, readings(kept.get("trace"), kept["rec"], kept["deliveries"])
-
-
-def main() -> None:
-    import argparse
-    import json
-    import sys
-    from pathlib import Path
-
-    t_start = time.perf_counter()
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--out", default=None, help="write the readings as JSON here too")
-    args = ap.parse_args()
-    root = Path(__file__).resolve().parent.parent
-    sys.path[0] = str(root)
-    from bench.entry import prepare
-
-    prepare()
-    from bench import harness
-
-    result, out = traced_run(args.workload, seed=args.seed, seconds=args.seconds, t_start=t_start)
-    harness.print_result(result)
-    for key, value in out.items():
-        harness.log(f"program {key}: {value}")
-    line = json.dumps({"workload": args.workload, "seed": args.seed, "program": out})
-    if args.out:
-        Path(args.out).parent.mkdir(parents=True, exist_ok=True)
-        Path(args.out).write_text(line + "\n")
-    print(line, flush=True)
-
-
-if __name__ == "__main__":
-    main()
+    return {
+        "skew_ms": pt.skew_ns / 1e6,
+        "window_s": (hi - lo) / 1e9,
+        "programs_s": by_program(pt, lo, hi),
+        "decode_by_scope_s": by_scope(pt, lo, hi),
+        "decode_top_ops": top_ops(pt, lo, hi),
+        "idle": split,
+        "idle_in_engine_step_named_share": named_child_share(split["inside"]),
+    }

@@ -1,8 +1,9 @@
 """What one run leaves for the metric readers, and the arithmetic they share.
 
 Every time here is the host's ``time.perf_counter()`` in seconds, except
-``admit_wait``, which is the engine's own clock difference (admission minus
-submission). The window is ``[t_open, t_close)``.
+``admit_wait`` and ``token_delay``, which are differences on the engine's own
+clock (admission minus submission; a token's receipt minus the program's
+stamp of its emission). The window is ``[t_open, t_close)``.
 """
 
 from __future__ import annotations
@@ -22,6 +23,7 @@ class RequestRecord:
     target: int  # output tokens asked for (EOS is unreachable)
     submit_t: Optional[float] = None  # when the client sent it
     token_t: list = field(default_factory=list)  # receipt time of each token
+    token_delay: list = field(default_factory=list)  # each token's receipt after its emission stamp
     tokens: list = field(default_factory=list)
     finish: Optional[str] = None  # the stream's finish reason
     req_id: Optional[int] = None
@@ -56,6 +58,7 @@ class RunRecord:
     counters_close: dict = field(default_factory=dict)
     trace: Optional[dict] = None  # bench/trace_reduce.py's reduction of a traced run
     trace_span: Optional[tuple] = None  # host times at which the reduced span opened and closed
+    program: Optional[object] = None  # bench/program_trace.py's ProgramTrace of a traced run
 
 
 def percentile(values, pct: float) -> Optional[float]:

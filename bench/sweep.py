@@ -18,6 +18,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import copy  # noqa: E402
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -43,12 +44,12 @@ def main() -> None:
     for rate in (float(r) for r in args.rates.split(",")):
         mix = copy.deepcopy(base.mix)
         mix["arrival"]["rate_per_s"] = rate
-        cell = spec.Cell(base.name, base.config, mix, base.chips, base.end_to_end, base.per_layer)
+        cell = dataclasses.replace(base, mix=mix)
         out = harness.run(
             args.workload, seed=args.seed, seconds=args.seconds, trace=False,
-            t_start=time.perf_counter(), cell=cell, keep_requests=True,
+            t_start=time.perf_counter(), cell=cell, keep_record=True,
         )
-        reqs = out["_requests"]
+        reqs = out["_record"].requests
         q = len(reqs) // 4
         wait = lambda rs: percentile([r.admit_wait for r in rs if r.admit_wait is not None], 50)
         ttft = [(r.token_t[0] - r.due_t) for r in reqs if r.token_t]

@@ -22,15 +22,6 @@ def test_too_few_chips_is_refused():
         harness.check_devices(4, require_tpu=False)
 
 
-def test_cell_on_several_chips_is_refused():
-    from bench.tests.tiny import tiny_cell
-
-    cell = tiny_cell("olmo-1b.offline")
-    four = spec.Cell(cell.name, cell.config, cell.mix, 4, cell.end_to_end, cell.per_layer)
-    with pytest.raises(SystemExit, match="asks for 4 chips"):
-        harness.run(cell.name, seed=1, seconds=1.0, trace=False, t_start=0.0, cell=four, require_tpu=False)
-
-
 def test_device_missing_from_peaks_is_refused():
     with pytest.raises(SystemExit, match="not in bench/peaks.json"):
         spec.peaks("cpu")

@@ -9,11 +9,8 @@ between the sound readings (under 0.01) and the control's (above 0.1)."""
 
 import time
 
-import jax.numpy as jnp
-import pytest
-
 from bench import harness
-from bench.tests.tiny import tiny_cell
+from bench.tests.tiny import alter_served_tokens, tiny_cell
 
 CPU_PEAKS = {"cpu": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
 TINY_LIMIT = 0.05
@@ -33,25 +30,13 @@ def test_sound_run_is_correct_and_its_control_is_not():
     assert not out["correct"], out["checks"]
     assert out["checks"]["served_logit_gap"]["value"] == max(cal["control_gaps"]) > TINY_LIMIT
     assert out["attempted"] > 0 and out["failed"] == 0
-    assert set(out["metrics"]) == {"ttft_p50_s", "ttft_p95_s", "itl_p95_s", "setup_s"}
+    assert set(out["metrics"]) == {"ttft_p50_s", "itl_p95_s", "setup_s"}
     assert sum(cal["sample_tokens"]) >= 50
     assert max(cal["gaps"]) <= TINY_LIMIT
 
 
 def test_token_altered_in_the_model_step_is_caught(monkeypatch):
-    import repro.serving.engine as engine
-
-    real = engine.decode_step
-
-    def altered(cfg, *a, **k):
-        logits, cache = real(cfg, *a, **k)
-        # every third batch row: the best token loses, the runner-up is served
-        best = jnp.argmax(logits, axis=-1)
-        rows = (jnp.arange(logits.shape[0]) % 3 == 0)[:, None]
-        hit = rows & (jnp.arange(logits.shape[-1])[None, :] == best[:, None])
-        return jnp.where(hit, -1e9, logits), cache
-
-    monkeypatch.setattr(engine, "decode_step", altered)
+    alter_served_tokens(monkeypatch)
     out = tiny_run("olmo-1b.offline", 2**31 + 12)
     assert not out["correct"]
     assert out["checks"]["served_logit_gap"]["value"] > TINY_LIMIT

@@ -5,10 +5,13 @@ from pathlib import Path
 
 import pytest
 
+import numpy as np
+
 from bench import program_trace as pt
+from bench import spec
 from bench import trace_reduce as tr
 from bench import work
-from bench.record import RunRecord, StepRecord
+from bench.record import RequestRecord, RunRecord, StepRecord
 
 PROBE = Path(__file__).parent / "data" / "probe.xplane.pb"
 HOST = tr.HOST_SPANS + (tr.WINDOW_SPAN,)
@@ -266,7 +269,7 @@ def test_decode_attn_roofline_of_known_steps():
     least = 0.0
     for keys in (300, 700):
         least += max(kv * keys / 32e9, 2 * 4.0 * 2 * 4 * keys / 64e12)
-    assert pt.decode_attn_least_s(SHAPES, [100, 200], PEAK) == pytest.approx(kv * 300 / 32e9)
+    assert work.decode_attn_least_s(SHAPES, [100, 200], PEAK) == pytest.approx(kv * 300 / 32e9)
     assert pt.decode_attn_roofline(t, rec) == pytest.approx(100 * least / 5000e-9)
     t.ops["/device:TPU:0"][5].reads = ("convert.48",)  # fusion.2, no op_name, reads attention
     pt.inherit_scopes(t.ops["/device:TPU:0"])
@@ -281,23 +284,93 @@ def test_token_delivery_counts_the_window_only():
     assert pt.token_delivery_p95_s([], 1.0, 2.0) is None
 
 
-def test_a_run_keeps_its_record_and_token_delays():
-    """Untraced on the CPU at a tiny size: the wrappers keep the run's
-    record and every token's delay after its emission stamp, then go."""
+def four_devices(t, rec):
+    """The same trace on four devices, each running the same ops, in a
+    record of four chips."""
+    for i in range(1, 4):
+        t.ops[f"/device:TPU:{i}"] = [pt.Op(**o.__dict__) for o in t.ops["/device:TPU:0"]]
+        t.runs[f"/device:TPU:{i}"] = list(t.runs["/device:TPU:0"])
+    rec.chips = 4
+    return t, rec
+
+
+def test_decode_attn_roofline_at_four_devices():
+    """Device time is averaged over the planes and the least time is over
+    four chips' peaks: a quarter of the one-chip share."""
+    t, rec = step_trace()
+    one = pt.decode_attn_roofline(t, rec)
+    assert pt.decode_attn_roofline(*four_devices(t, rec)) == pytest.approx(one / 4)
+    assert pt.by_program(t, 0, 1e6) == pytest.approx(pt.by_program(*step_trace()[:1], 0, 1e6))
+
+
+def test_readers_of_the_program_trace():
+    t, rec = step_trace()
+    rec.program = t
+    read = lambda name: spec.metric_reader(name)(rec)
+    assert read("decode_attn_roofline.offline") == pytest.approx(pt.decode_attn_roofline(t, rec))
+    assert read("host_step_ms.offline") == pytest.approx(pt.host_step_ms(t, rec))
+    rec.program = pt.ProgramTrace()  # a trace with no device plane and no spans
+    assert read("decode_attn_roofline.offline") is None
+    assert read("host_step_ms.offline") is None
+    rec.program = None
+    assert read("decode_attn_roofline.offline") is None and read("host_step_ms.offline") is None
+
+
+def test_token_delivery_reader_pairs_each_receipt_with_its_delay():
+    t, rec = step_trace()
+    rec.t_open, rec.t_close = 1.0, 2.0
+    a = RequestRecord(0.0, (2,), 3, token_t=[0.5, 1.2, 1.5], token_delay=[9.0, 0.01, 0.03])
+    b = RequestRecord(0.0, (2,), 2, token_t=[1.7, 2.5], token_delay=[0.02, 9.0])
+    rec.requests = [a, b]
+    assert spec.metric_reader("token_delivery_p95_s")(rec) == pytest.approx(np.percentile([0.01, 0.03, 0.02], 95))
+    rec.requests = []
+    assert spec.metric_reader("token_delivery_p95_s")(rec) is None
+
+
+def no_device_plane_allowed(monkeypatch):
+    """A CPU trace has no TPU plane: reduce it to an idle device so the rest
+    of a traced run can go on."""
+    real = tr.reduce
+
+    def reduce(ev, top=10):
+        if ev.device_ops:
+            return real(ev, top)
+        lo, hi = tr.window_of(ev)
+        return {"busy_s": 0.0, "window_s": (hi - lo) / 1e9, "idle_share": 1.0, "devices": 0, "device_ops": [],
+                "idle_gaps": [], "idle_by_span": {}, "window_ns": (lo, hi), "busy_union": {"/device:TPU:0": []}}
+
+    monkeypatch.setattr(tr, "reduce", reduce)
+
+
+@pytest.mark.parametrize(
+    "workload, reads",
+    [("olmo-1b.offline", "host_step_ms.offline"), ("olmo-1b.shared-prefix", "token_delivery_p95_s")],
+)
+def test_a_traced_run_keeps_the_program_trace_in_its_record(monkeypatch, workload, reads):
+    """Traced on the CPU at a tiny size: the record carries the program's
+    spans read from the trace before it was deleted, the per-layer metric
+    read from them is in the result, and the one that needs a device plane
+    is left out."""
     import time
 
-    from bench import harness, trace_reduce
+    from bench import harness
     from bench.tests.test_bench_faults import CPU_PEAKS, TINY_LIMIT
     from bench.tests.tiny import tiny_cell
-    from repro.serving.async_engine import AsyncEngine
 
-    before = (trace_reduce.find_xplane, harness.RunRecord, AsyncEngine.submit_stream)
-    result, out = pt.traced_run(
-        "olmo-1b.shared-prefix", seed=2**31 + 21, seconds=3.0, trace=False, t_start=time.perf_counter(),
-        cell=tiny_cell("olmo-1b.shared-prefix", limit=TINY_LIMIT), require_tpu=False, peaks_table=CPU_PEAKS,
+    no_device_plane_allowed(monkeypatch)
+    cell = tiny_cell(workload, limit=TINY_LIMIT)
+    if cell.mix["arrival"]["kind"] == "backlog":
+        cell.mix["arrival"]["count"] = 200  # still decoding in the traced middle of the window
+    out = harness.run(
+        workload, seed=2**31 + 21, seconds=3.0, trace=True, t_start=time.perf_counter(),
+        cell=cell, require_tpu=False, peaks_table=CPU_PEAKS, keep_record=True,
     )
-    assert (trace_reduce.find_xplane, harness.RunRecord, AsyncEngine.submit_stream) == before
-    assert result["correct"], result["checks"]
-    assert set(out) == {"per_layer"}
-    delay = out["per_layer"]["token_delivery_p95_s"]
-    assert delay is not None and 0 <= delay < 1.0
+    assert out["correct"], out["checks"]
+    rec = out["_record"]
+    assert isinstance(rec.program, pt.ProgramTrace)
+    names = {s.name for s in rec.program.all_spans()}
+    assert {"engine.step", "engine.emit"} <= names
+    assert any(n == tr.WINDOW_SPAN for _, _, n in rec.program.host)
+    assert all(len(r.token_delay) == len(r.token_t) for r in rec.requests)
+    assert 0 < out["metrics"][reads]["value"] < 1e3
+    assert "decode_attn_roofline.offline" not in out["metrics"]

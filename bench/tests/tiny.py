@@ -1,9 +1,11 @@
 """Tiny cells for the CPU tests: the real configuration and mix files with
-every size cut down, so a whole run takes seconds on the CPU backend."""
+every size cut down, so a whole run takes seconds on the CPU backend, and
+the faults planted in the program that a run has to catch."""
 
 from __future__ import annotations
 
 import copy
+import dataclasses
 
 from bench import spec
 
@@ -23,10 +25,15 @@ TINY_LENGTHS = {
 }
 
 
-def tiny_cell(workload: str, *, rate_per_s: float = 4.0, limit: float = 1.0) -> spec.Cell:
+def tiny_cell(workload: str, *, rate_per_s: float = 4.0, limit: float = 1.0, chips: int = 1) -> spec.Cell:
+    """``chips`` > 1 lays the cell out as ``(data=1, model=chips)`` with as
+    many K/V heads as query heads, so every head count divides the mesh."""
     real = spec.load_cell(workload)
     cfg = copy.deepcopy(real.config)
     cfg.update(TINY_SIZES)
+    if chips > 1:
+        cfg["num_key_value_heads"] = cfg["num_attention_heads"]
+        cfg["mesh"] = {"data": 1, "model": chips}
     cfg["deployment"].update(TINY_DEPLOYMENT)
     cfg["limits"]["served_logit_gap"] = limit
     mix = copy.deepcopy(real.mix)
@@ -39,4 +46,23 @@ def tiny_cell(workload: str, *, rate_per_s: float = 4.0, limit: float = 1.0) -> 
         mix["tenants"]["count"] = 3
         mix["tenants"]["system"] = {"dist": "uniform", "min": 40, "max": 90}
         mix["prompt"] = {"dist": "uniform", "min": 4, "max": 16}
-    return spec.Cell(real.name, cfg, mix, 1, real.end_to_end, real.per_layer)
+    return dataclasses.replace(real, config=cfg, mix=mix, chips=chips)
+
+
+def alter_served_tokens(monkeypatch) -> None:
+    """Plant a fault in the program's model step: in every third batch row
+    of each decode step, the best token loses and the runner-up is served."""
+    import jax.numpy as jnp
+
+    import repro.serving.engine as engine
+
+    real = engine.decode_step
+
+    def altered(cfg, *a, **k):
+        logits, cache = real(cfg, *a, **k)
+        best = jnp.argmax(logits, axis=-1)
+        rows = (jnp.arange(logits.shape[0]) % 3 == 0)[:, None]
+        hit = rows & (jnp.arange(logits.shape[-1])[None, :] == best[:, None])
+        return jnp.where(hit, -1e9, logits), cache
+
+    monkeypatch.setattr(engine, "decode_step", altered)

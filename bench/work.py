@@ -43,7 +43,8 @@ class Shapes:
 
     @property
     def head_params(self) -> int:
-        """The output head, which is the embedding table (tied)."""
+        """The output head (the embedding table where it is tied); an untied
+        model's table is only gathered from, a row a token."""
         return self.d * self.vocab
 
     @property
@@ -73,8 +74,20 @@ def prefill_flops(s: Shapes, start: int, end: int) -> float:
 
 
 def decode_step_bytes(s: Shapes, keys: list[int]) -> float:
-    """Least HBM traffic of one decode step: every weight once (the tied
-    output head is the embedding table) and the K/V of each slot's live
-    context: ``keys - 1`` positions read and the new one written."""
+    """Least HBM traffic of one decode step: every weight once (the output
+    head's table, not the rows an untied embedding gathers) and the K/V of
+    each slot's live context: ``keys - 1`` positions read and the new one
+    written."""
     weights = (s.layers * s.layer_params + s.head_params) * BF16
     return weights + s.kv_bytes_per_token * sum(keys)
+
+
+def decode_attn_least_s(s: Shapes, keys: list[int], peak: dict, chips: int = 1) -> float:
+    """Least time of one decode step's attention at the chips' peaks: the
+    decoding slots' live K/V (every layer, bf16) over HBM bandwidth, or
+    4 * heads * head_dim FLOPs per attended key over the bf16 peak,
+    whichever is longer."""
+    n = sum(keys)
+    by_bytes = s.kv_bytes_per_token * n / (chips * peak["hbm_bytes_per_s"])
+    by_flops = s.layers * 4.0 * s.heads * s.head_dim * n / (chips * peak["bf16_flops_per_s"])
+    return max(by_bytes, by_flops)
